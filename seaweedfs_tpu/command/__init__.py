@@ -916,6 +916,8 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv: list[str] | None = None) -> int:
     import sys as _sys
     argv = list(_sys.argv[1:] if argv is None else argv)
+    from ..util.compile_cache import place_compile_cache
+    place_compile_cache()
     # global verbosity: bare -v or glog-style -v=N; a following token is
     # NEVER consumed (so `master -v 100` can't silently swallow an
     # argument meant for the subcommand)
@@ -956,15 +958,13 @@ def main(argv: list[str] | None = None) -> int:
                                       str(sec.get("grpc.cert") or ""),
                                       str(sec.get("grpc.key") or "")))
     # global EC backend pin on every verb: -ec.backend
-    # native|numpy|pallas|jax|auto.  Sets WEED_EC_BACKEND so the
-    # bandwidth-aware picker (ops.codec.device_link_ok) skips its probe —
-    # the operator's override for hosts where the probe would guess wrong
+    # native|numpy|pallas|jax|auto.  Sets WEED_EC_BACKEND, which
+    # overrides the platform rule (pallas on a TPU, native on a CPU)
     for i, a in enumerate(list(argv)):
         if a == "-ec.backend" and i + 1 < len(argv):
             value = argv[i + 1]
             del argv[i:i + 2]
-            from ..ops.codec import reset_backend_probe, \
-                validate_ec_backend_pin
+            from ..ops.codec import validate_ec_backend_pin
             prior = os.environ.get("WEED_EC_BACKEND")
             os.environ["WEED_EC_BACKEND"] = value
             try:
@@ -977,7 +977,6 @@ def main(argv: list[str] | None = None) -> int:
                 else:
                     os.environ["WEED_EC_BACKEND"] = prior
                 raise
-            reset_backend_probe()
             break
     # global profiling hooks on every verb (reference
     # util/grace/pprof.go:11-55): -cpuprofile FILE / -memprofile FILE

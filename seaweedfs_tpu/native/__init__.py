@@ -2,10 +2,11 @@
 
 The reference ships as a single Go binary whose only "native" hot path is the
 vendored SIMD Reed-Solomon codec; here the TPU owns the codec and this package
-owns the host-side hot loops: CRC32C needle checksums, the compact needle map,
-and streaming IO. Everything has a pure-Python fallback so the framework runs
-unbuilt; `build()` compiles the .so on demand with g++ (no pip deps — plain
-ctypes ABI).
+owns the host-side hot loops: CRC32C needle checksums, the CPU GF(2^8) codec,
+and the TCP/HTTP frame loop. Everything has a pure-Python fallback so the
+framework runs unbuilt; the .so files are compiled on demand with g++/gcc on
+the host that runs them (no pip deps — plain ctypes ABI), keyed by source
+content and host CPU.
 """
 
 from __future__ import annotations
@@ -16,44 +17,76 @@ import subprocess
 import threading
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_DIR, "libseaweed_native.so")
-_SOURCES = ["crc32c.cpp", "needle_map.cpp", "rs_gf256.cpp"]
+_SOURCES = ["crc32c.cpp", "rs_gf256.cpp"]
 _lock = threading.Lock()
 _lib = None
 _tried = False
 
 
-def build(force: bool = False) -> str | None:
-    """Compile the native library if missing/stale. Returns path or None."""
-    srcs = [os.path.join(_DIR, s) for s in _SOURCES if os.path.exists(os.path.join(_DIR, s))]
-    if not srcs:
-        return None
-    if not force and os.path.exists(_SO):
-        so_mtime = os.path.getmtime(_SO)
-        if all(os.path.getmtime(s) <= so_mtime for s in srcs):
-            return _SO
-    tmp = _SO + ".tmp"
-    cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-           "-o", tmp] + srcs
+def _host_cpu() -> str:
+    """What -march=native compiles for: the machine and its CPU flags."""
+    import platform
+    ident = platform.machine()
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _SO)
+        with open("/proc/cpuinfo", encoding="utf-8") as f:
+            for line in f:
+                if line.startswith(("model name", "flags", "Features")):
+                    ident += "|" + line.strip()
+                if line.strip() == "":
+                    break      # first processor block only
+    except OSError:
+        pass
+    return ident
+
+
+def _compile(stem: str, srcs: list[str], cmd: list[str]) -> "str | None":
+    """Build `srcs` into <stem>.<key>.so, where the key hashes the source
+    bytes, the command and the host CPU; reuse it when it exists.  A .so
+    built on another machine (or from other sources) carries another key,
+    so it is never run here.  Returns the path, or None when the build
+    fails (callers then take their pure-Python fallbacks)."""
+    import glob
+    import hashlib
+    h = hashlib.sha256("\0".join(cmd + [_host_cpu()]).encode())
+    for src in srcs:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    so = os.path.join(_DIR, f"{stem}.{h.hexdigest()[:16]}.so")
+    if os.path.exists(so):
+        return so
+    tmp = f"{so}.{os.getpid()}.tmp"   # parallel test workers build too
+    try:
+        subprocess.run(cmd + srcs + ["-o", tmp], check=True,
+                       capture_output=True, timeout=120)
+        os.replace(tmp, so)
     except Exception as e:
         try:
             os.remove(tmp)
         except OSError:
             pass
-        # recompile failed: keep serving the existing (stale) .so rather
-        # than regressing every native path to the Python fallbacks —
-        # but LOUDLY, or a broken source edit would test the old binary
         import warnings
         detail = getattr(e, "stderr", b"")
         detail = detail.decode(errors="replace")[-400:] \
             if isinstance(detail, bytes) else str(e)
-        warnings.warn(f"native rebuild failed, serving stale .so: "
-                      f"{detail}", RuntimeWarning)
-        return _SO if os.path.exists(_SO) else None
-    return _SO
+        warnings.warn(f"native build of {stem} failed, using the Python "
+                      f"fallbacks: {detail}", RuntimeWarning)
+        return None
+    for old in glob.glob(os.path.join(_DIR, f"{stem}.*.so")):
+        if old != so:
+            try:
+                os.remove(old)
+            except OSError:
+                pass
+    return so
+
+
+def build() -> str | None:
+    """Compile the native library for this host (or reuse this host's
+    build). Returns its path or None."""
+    srcs = [os.path.join(_DIR, s) for s in _SOURCES]
+    return _compile("libseaweed_native", srcs,
+                    ["g++", "-O3", "-march=native", "-shared", "-fPIC",
+                     "-std=c++17"])
 
 
 def _load():
@@ -152,43 +185,16 @@ def lib():
 # -- CPython extension for the TCP frame hot loop --------------------------
 # (separate .so: it links against Python.h, unlike the plain-ABI library)
 
-_FP_SO = os.path.join(_DIR, "_seaweed_fastpath.so")
 _fp = None
 _fp_tried = False
 
 
 def _build_fastpath() -> "str | None":
-    src = os.path.join(_DIR, "fastpath.c")
-    if not os.path.exists(src):
-        return None
-    if os.path.exists(_FP_SO) and \
-            os.path.getmtime(src) <= os.path.getmtime(_FP_SO):
-        return _FP_SO
     import sysconfig
     inc = sysconfig.get_paths()["include"]
-    tmp = _FP_SO + ".tmp"
-    cmd = ["gcc", "-O2", "-march=native", "-shared", "-fPIC",
-           f"-I{inc}", src, "-o", tmp]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _FP_SO)
-    except Exception as e:
-        try:
-            os.remove(tmp)
-        except OSError:
-            pass
-        # same invariant as build(): serving a stale .so is better than
-        # regressing to the Python fallbacks, but NEVER silently — a
-        # broken source edit must not quietly test the old binary
-        import warnings
-        detail = getattr(e, "stderr", b"")
-        detail = detail.decode(errors="replace")[-400:] \
-            if isinstance(detail, bytes) else str(e)
-        warnings.warn(f"fastpath rebuild failed, "
-                      f"{'serving stale .so' if os.path.exists(_FP_SO) else 'disabled'}: "
-                      f"{detail}", RuntimeWarning)
-        return _FP_SO if os.path.exists(_FP_SO) else None
-    return _FP_SO
+    return _compile("_seaweed_fastpath", [os.path.join(_DIR, "fastpath.c")],
+                    ["gcc", "-O2", "-march=native", "-shared", "-fPIC",
+                     f"-I{inc}"])
 
 
 def fastpath():

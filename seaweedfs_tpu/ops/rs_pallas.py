@@ -41,11 +41,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import gf256
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions; accept
-# either so the kernels (and their interpret-mode tests) run on both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 LANE = 128
 DEFAULT_BLOCK_B = 2048
 
@@ -125,7 +120,7 @@ def gf_matmul_bits_pallas(mbits_pm: jax.Array, data: jax.Array, *,
         out_specs=pl.BlockSpec((1, mo, block_b), lambda i, j: (i, 0, j),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((v, mo, b), jnp.uint8),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(mbits_pm, data)
@@ -157,7 +152,7 @@ def _gf2_matmul_kernel_sm(mbits_ref, data_ref, out_ref, *, ki: int,
     out_ref[...] = jnp.sum(v << out_shifts, axis=0).astype(jnp.uint8)
 
 
-SM_DEFAULT_BLOCK_B = 512  # swept best on v5e (32 GB/s with int8)
+SM_DEFAULT_BLOCK_B = 512  # swept best on v5e (retired setup; int8)
 
 
 @functools.partial(jax.jit,
@@ -194,7 +189,7 @@ def gf_matmul_bits_pallas_sm(mbits_pm: jax.Array, data: jax.Array, *,
                                lambda i, j: (0, i, j),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((mo, v, b), jnp.uint8),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(mbits_pm, data)
@@ -234,7 +229,7 @@ def gf_matmul_bits_pallas_cols(mbits_pm: jax.Array, data: jax.Array, *,
         out_specs=pl.BlockSpec((mo, vblock, LANE), lambda i: (0, i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((mo, x, LANE), jnp.uint8),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(mbits_pm, data)
@@ -256,8 +251,9 @@ def _block_vmem_bytes(ki: int, mo: int, lanes: int) -> int:
 def sm_block_b_for(ki: int, mo: int) -> int:
     """Geometry-aware block_b for the shard-major kernel.
 
-    ki <= 16 keeps the swept 512 (the v5e optimum measured across
-    RS(10,4)..RS(16,8), BENCH_r05) — at 8*ki <= 128 the contraction dim
+    ki <= 16 keeps the swept 512 (the v5e optimum across RS(10,4)..
+    RS(16,8) on a retired setup; not yet re-swept on this machine's
+    chip) — at 8*ki <= 128 the contraction dim
     fills at most one MXU pass and the sweep already covered the range.
     Wider stripes (RS(28,4) class) grow every per-block tensor linearly
     in ki, so the same block_b crowds the double-buffered operands out
@@ -344,6 +340,14 @@ def encode_pallas(parity_bits: np.ndarray, data: jax.Array, *,
 # by construction.
 
 CLAY_FUSED_CB = 128   # minimum column tile (one u8 lane tile)
+
+# Mosaic's default scoped-VMEM limit on v5e is 16 MiB of the chip's 128
+# MiB.  The fused clay kernels hold a whole [alpha, cb] column tile per
+# operand row, so alpha = 512 geometries (clay (16,8): 35 MiB encode,
+# 18 MiB repair by the v5e compiler's own count) are refused at the
+# default even at the 128-lane floor of cb.  One raised limit for every
+# geometry; tests/test_tpu_compile.py compiles (10,4) and (16,8) under it.
+CLAY_FUSED_VMEM_LIMIT = 64 << 20
 
 
 def clay_fused_cb_for(rows: int, w_a: int) -> int:
@@ -457,8 +461,9 @@ def clay_fused_encode_pallas(rbits_pm: jax.Array, data4: jax.Array, *,
         out_specs=pl.BlockSpec((q, 1, alpha, cb), lambda i, j: (0, i, 0, j),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((q, n_win, alpha, w_a), jnp.uint8),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=CLAY_FUSED_VMEM_LIMIT),
         interpret=interpret,
     )(rbits_pm, data4)
 
@@ -564,7 +569,8 @@ def clay_fused_repair_pallas(rbits_pm: jax.Array, x4: jax.Array, *,
         out_specs=pl.BlockSpec((1, alpha, cb), lambda i, j: (i, 0, j),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n_win, alpha, w_a), jnp.uint8),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=CLAY_FUSED_VMEM_LIMIT),
         interpret=interpret,
     )(rbits_pm, x4)

@@ -22,16 +22,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 exports shard_map at top level with check_vma
-    from jax import shard_map
-except ImportError:  # jax 0.4.x: experimental module, kwarg is check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    def shard_map(f, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_04(f, **kwargs)
-
 
 def make_mesh(n_volume: int | None = None, n_byte: int = 1,
               devices=None) -> Mesh:

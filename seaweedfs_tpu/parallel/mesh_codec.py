@@ -38,8 +38,8 @@ import time as _time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from .mesh import shard_map
 
 from ..ops import rs_jax, rs_matrix, rs_pallas
 from . import sharded_codec
@@ -329,13 +329,39 @@ def multi_device_host() -> bool:
         return False
 
 
+def mesh_picked() -> bool:
+    """The production rule for RS, clay and LRC alike: ride the device
+    mesh when this process sees more than one device (driver dryrun,
+    multi-chip hosts).  A CPU-pinned WEED_EC_BACKEND keeps a TPU mesh
+    host on the pinned CPU codec (ops.codec.mesh_compute_ok)."""
+    from ..ops.codec import mesh_compute_ok
+    return multi_device_host() and mesh_compute_ok()
+
+
 def codec_for_devices(k: int, m: int, *, kind: str = "vandermonde"):
-    """The production codec picker: MeshCodec when this process sees more
-    than one device (driver dryrun, multi-chip hosts), single-chip RSCodec
-    otherwise.  RSCodec's "auto" (and the mesh gate here) are
-    bandwidth-aware — a TPU behind a losing host<->device link falls back
-    to the native CPU codec (ops.codec.device_link_ok)."""
-    from ..ops.codec import RSCodec, mesh_compute_ok
-    if multi_device_host() and mesh_compute_ok():
+    """The production codec picker: MeshCodec under mesh_picked(),
+    single-chip RSCodec otherwise."""
+    from ..ops.codec import RSCodec
+    if mesh_picked():
         return MeshCodec(k, m, kind=kind)
     return RSCodec(k, m, kind=kind)
+
+
+def ec_backend_status() -> dict:
+    """What EC work in this process runs on, for the servers' /status:
+    the backend codec_for_devices picks plus the device JAX opened, so a
+    CPU codec on a TPU host is visible instead of silent.  Until EC work
+    has opened a device it says so, rather than open the chip itself: a
+    health check must not be what first takes it."""
+    from jax._src import xla_bridge
+
+    from ..ops.codec import ec_backend_override, resolve_backend
+    pin = ec_backend_override() or "auto"
+    if not xla_bridge.backends_are_initialized():
+        return {"opened": False, "pin": pin}
+    devices = jax.devices()
+    return {"opened": True,
+            "backend": "mesh" if mesh_picked() else resolve_backend(),
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "devices": len(devices), "pin": pin}

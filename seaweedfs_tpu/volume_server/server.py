@@ -518,9 +518,11 @@ class VolumeServer:
             if merged is not None:
                 return merged
         hb = self.store.collect_heartbeat()
+        from ..parallel.mesh_codec import ec_backend_status
         return Response.json({"Version": "seaweedfs-tpu",
                               "Volumes": [vars(v) for v in hb.volumes],
-                              "NeedleCache": self.needle_cache.stats})
+                              "NeedleCache": self.needle_cache.stats,
+                              "Ec": ec_backend_status()})
 
     def _parse_fid_path(self, path: str) -> FileId:
         # /3,01637037d6 (volume_server_handlers_read.go:43 parsing)

@@ -309,6 +309,10 @@ class ShardedVolumeServer:
         self.tcp = _PortShim(self._worker_ports[0]["tcp"])
         if not self.reuseport:
             self._start_fd_pass()
+        LOG.warning("volume workers (%d) run EC on the CPU codec with "
+                    "JAX_PLATFORMS=cpu; EC on an accelerator needs a "
+                    "single-process volume server (WEED_VOLUME_WORKERS=1)",
+                    self.workers)
         for i in range(self.workers):
             self._spawn_worker(i)
         self._wait_ready(ready_timeout)
@@ -401,6 +405,11 @@ class ShardedVolumeServer:
             json.dump(self._worker_config(i), f)
         self._cfg_paths[i] = cfg_path
         env = dict(os.environ)
+        # one process per chip: N workers cannot share one TPU, and a
+        # worker that lost the race for it would fall back to the CPU
+        # in silence.  Workers never open the accelerator; their EC
+        # runs on the CPU codec, which start() logs and /status shows.
+        env["JAX_PLATFORMS"] = "cpu"
         pkg_root = os.path.dirname(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))))
         env["PYTHONPATH"] = pkg_root + os.pathsep \
@@ -977,6 +986,7 @@ class ShardedVolumeServer:
                 continue
             merged["Volumes"].extend(d.get("Volumes", []))
             merged["NeedleCache"].append(d.get("NeedleCache", {}))
+            merged.setdefault("Ec", []).append(d.get("Ec"))
         return Response.json(merged)
 
     def _http_metrics(self, req: Request) -> Response:

@@ -175,3 +175,25 @@ def test_layer_mds_cols_pads_unaligned_x(monkeypatch):
     want = gf256.matmul(np.ascontiguousarray(R),
                         u.reshape(k0, -1)).reshape(m, 24, 128)
     assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("backend,block_b,width,padded", [
+    ("pallas", 256, 1, 2048),         # floor: one kernel block (8*block_b)
+    ("pallas", 256, 5000, 6144),      # 3 blocks: below 16, exact
+    ("pallas", 512, 17 * 4096 + 1, 18 * 4096),   # 4 significant bits
+    ("pallas", 384, 40 * 3072, 40 * 3072),       # non-power-of-two block
+    ("pallas", 512, 1 << 20, 1 << 20),
+    ("jax", 512, 129, 256),
+    ("jax", 512, 100, 128),
+    ("jax", 512, 1000 * 128 + 5, 1024 * 128),
+])
+def test_device_widths_bucket(backend, block_b, width, padded):
+    """Degraded reads come in every needle size; each padded width is a
+    device program to compile, so widths fall in 8 buckets per octave
+    of kernel blocks, never padding more than 12.5% past one block."""
+    codec = RSCodec(10, 4, backend=backend, block_b=block_b)
+    out, b = codec._pad(np.zeros((10, width), np.uint8))
+    assert b == width and out.shape == (10, padded)
+    mult = 8 * block_b if backend == "pallas" else 128
+    assert padded % mult == 0
+    assert padded - width < max(mult, width / 8)

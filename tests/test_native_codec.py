@@ -81,3 +81,27 @@ def _timed(fn) -> float:
     t0 = time.perf_counter()
     fn()
     return time.perf_counter() - t0
+
+
+def test_build_is_keyed_by_source_and_host_cpu(tmp_path, monkeypatch):
+    """A .so built elsewhere (other CPU, other sources) is never run
+    here: the file name carries a hash of both, so only this host's
+    own build is reused."""
+    import glob
+    import os
+    import shutil
+    src = tmp_path / "crc32c.cpp"
+    shutil.copy(os.path.join(native._DIR, "crc32c.cpp"), src)
+    monkeypatch.setattr(native, "_DIR", str(tmp_path))
+    cmd = ["g++", "-O1", "-shared", "-fPIC", "-std=c++17"]
+    so = native._compile("libtest", [str(src)], cmd)
+    assert so and os.path.basename(so).startswith("libtest.")
+    assert native._compile("libtest", [str(src)], cmd) == so   # reused
+    # the same sources on another CPU name another file ...
+    monkeypatch.setattr(native, "_host_cpu", lambda: "other-cpu")
+    other = native._compile("libtest", [str(src)], cmd)
+    assert other and other != so
+    # ... and a rebuild drops the stale one
+    assert glob.glob(str(tmp_path / "libtest.*.so")) == [other]
+    src.write_text(src.read_text() + "\n// edited\n")
+    assert native._compile("libtest", [str(src)], cmd) != other

@@ -6,7 +6,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from seaweedfs_tpu.parallel.mesh import shard_map
+from jax import shard_map
 
 from seaweedfs_tpu.ops import gf256, rs_matrix
 from seaweedfs_tpu.parallel import mesh as meshlib

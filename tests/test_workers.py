@@ -111,6 +111,17 @@ def test_merged_status_and_metrics(sharded):
     assert status == 200
     merged = json.loads(body)
     assert merged["Workers"]["workers"] == 2
+    # one process per chip: workers never open the accelerator, and
+    # each says which codec its EC work runs on
+    assert len(merged["Ec"]) == 2
+    for ec in merged["Ec"]:
+        assert ec["pin"] == "auto"
+        if ec["opened"]:
+            assert ec["platform"] == "cpu"
+            assert ec["backend"] in ("mesh", "native", "jax")
+    for proc in vs._procs.values():
+        with open(f"/proc/{proc.pid}/environ", "rb") as f:
+            assert b"JAX_PLATFORMS=cpu" in f.read().split(b"\0")
     status, body, _ = http_request(
         f"http://{vs.worker_http_addr(0)}/status?worker_local=1")
     local = json.loads(body)
