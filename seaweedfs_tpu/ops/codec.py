@@ -26,11 +26,13 @@ import functools
 import os
 import threading
 import time
+from contextlib import contextmanager, nullcontext
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..util import tracing
 from . import gf256, rs_jax, rs_matrix, rs_pallas
 
 
@@ -75,6 +77,17 @@ _codec_metrics_lock = threading.Lock()
 # everything in two buckets
 _CODEC_BUCKETS = [0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0]
 
+_STAGE_HELP = {
+    "gather": "EC codec inputs copied into the host buffer (encode: "
+              "the .dat batch; degraded read: the k survivor intervals)",
+    "pack": "EC codec call from issue until the device dispatch returns "
+            "(stack, pad, relayout, bit matrix, host-to-device copy)",
+    "wait": "EC codec call blocked on the device result",
+    "unpack": "EC codec result on the host to the returned arrays",
+    "write": "EC shard-file writes behind codec calls",
+    "cpu": "calling thread's CPU time in EC codec pack, wait and unpack",
+}
+
 
 class _CodecMetrics:
     def __init__(self):
@@ -101,6 +114,14 @@ class _CodecMetrics:
             "seaweedfs_codec_dispatch_volumes_total",
             "volumes carried by EC codec dispatches",
             ["backend", "op"])
+        # where a codec call's host time goes, one series per stage
+        # (see codec_stage / device_stage); wall minus cpu over pack,
+        # wait and unpack is time spent blocked on the device or the GIL
+        self.stages = {
+            stage: self.registry.counter(
+                f"seaweedfs_codec_{stage}_seconds_total", help_text,
+                ["backend", "op"])
+            for stage, help_text in _STAGE_HELP.items()}
 
     def observe(self, backend: str, op: str, nbytes: int,
                 seconds: float, volumes: int = 1) -> None:
@@ -132,6 +153,36 @@ def metered_fetch(fetch, backend: str, op: str, nbytes: int, t0: float,
                                 time.perf_counter() - t0, volumes=volumes)
         return out
     return timed
+
+
+def metrics_backend(codec) -> str:
+    """The `backend` label of a codec's calls in the registry:
+    rs_<executor> for RSCodec/MeshCodec, the family for the clay/LRC
+    window codecs."""
+    backend = getattr(codec, "backend", "")
+    return backend if backend in ("clay", "lrc") else f"rs_{backend}"
+
+
+def codec_stage(stage: str, backend: str, op: str) -> tracing.stage:
+    """tracing.stage("codec.<stage>") whose wall time also feeds
+    seaweedfs_codec_<stage>_seconds_total{backend, op}."""
+    counter = codec_metrics().stages[stage]
+    return tracing.stage("codec." + stage, observe=lambda seconds:
+                         counter.inc(backend, op, value=seconds))
+
+
+@contextmanager
+def device_stage(stage: str, backend: str, op: str):
+    """One host stage of a device codec call (pack, wait, unpack): its
+    wall time as codec_stage, and the calling thread's CPU time in it
+    into seaweedfs_codec_cpu_seconds_total."""
+    c0 = time.thread_time()
+    try:
+        with codec_stage(stage, backend, op):
+            yield
+    finally:
+        codec_metrics().stages["cpu"].inc(
+            backend, op, value=time.thread_time() - c0)
 
 
 # -- backend selection ------------------------------------------------------
@@ -289,19 +340,27 @@ class RSCodec:
             arr = np.pad(arr, [(0, 0)] * (arr.ndim - 1) + [(0, pad)])
         return arr, b
 
+    def _stage(self, stage: str, op: str):
+        """device_stage of this codec's calls; the CPU backends compute
+        inside the call and record no pack, wait or unpack."""
+        if self.backend in _CPU_BACKENDS:
+            return nullcontext()
+        return device_stage(stage, f"rs_{self.backend}", op)
+
     def _matmul_begin(self, bits_shard_major: np.ndarray, mo: int,
-                      inputs: np.ndarray):
+                      inputs: np.ndarray, op: str):
         """Dispatch out = M ∘GF∘ inputs[..., KI, B] to the chosen backend.
 
         Returns a zero-arg fetch() -> np.ndarray.  On device backends the
         transfer + kernel are ISSUED here (JAX dispatch is async) and only
         fetch() blocks on the result — the seam the pipelined disk paths in
         storage/ec/encoder.py use to overlap disk reads, device compute and
-        shard-file writes."""
+        shard-file writes.  fetch() times its `wait` and `unpack` stages
+        under `op`."""
         squeeze = inputs.ndim == 2
         if squeeze:
             inputs = inputs[None]
-        if self.backend in ("numpy", "native"):
+        if self.backend in _CPU_BACKENDS:
             M = np.asarray(bits_shard_major)  # here: the GF matrix itself
             if self.backend == "native":
                 from .. import native
@@ -330,22 +389,23 @@ class RSCodec:
                 interpret=self.interpret)
 
             def fetch():
-                out = rs_pallas.from_sm_layout(
-                    np.asarray(jax.device_get(dev)), lead, bp)
-                out = out[..., :b]
-                return out[0] if squeeze else out
+                with self._stage("wait", op):
+                    host = np.asarray(jax.device_get(dev))
+                with self._stage("unpack", op):
+                    out = rs_pallas.from_sm_layout(host, lead, bp)
+                    out = out[..., :b]
+                    return out[0] if squeeze else out
             return fetch
         dev = rs_jax.gf_matmul_bits(
             jnp.asarray(bits_shard_major), jnp.asarray(padded))
 
         def fetch():
-            out = np.asarray(jax.device_get(dev))[..., :b]
-            return out[0] if squeeze else out
+            with self._stage("wait", op):
+                host = np.asarray(jax.device_get(dev))
+            with self._stage("unpack", op):
+                out = host[..., :b]
+                return out[0] if squeeze else out
         return fetch
-
-    def _matmul(self, bits_shard_major: np.ndarray, mo: int,
-                inputs: np.ndarray) -> np.ndarray:
-        return self._matmul_begin(bits_shard_major, mo, inputs)()
 
     def _parity_bits_pm(self):
         """Cached device-resident plane-major parity bit-matrix (pallas only).
@@ -371,12 +431,13 @@ class RSCodec:
         compute eagerly and fetch() is a no-op — same contract either way,
         so pipeline code needs no backend branches."""
         t0 = time.perf_counter()
-        data = np.asarray(data, dtype=np.uint8)
-        assert data.shape[-2] == self.k, f"expected {self.k} data shards"
-        if self.backend in ("numpy", "native"):
-            fetch = self._matmul_begin(self.gen[self.k:], self.m, data)
-        else:
-            fetch = self._matmul_begin(self._parity_bits, self.m, data)
+        with self._stage("pack", "encode"):
+            data = np.asarray(data, dtype=np.uint8)
+            assert data.shape[-2] == self.k, \
+                f"expected {self.k} data shards"
+            bits = self.gen[self.k:] if self.backend in _CPU_BACKENDS \
+                else self._parity_bits
+            fetch = self._matmul_begin(bits, self.m, data, "encode")
         volumes = int(np.prod(data.shape[:-2], dtype=np.int64)) \
             if data.ndim > 2 else 1
         return metered_fetch(fetch, f"rs_{self.backend}", "encode",
@@ -419,22 +480,23 @@ class RSCodec:
         if not targets:
             res = list(shards)
             return lambda: res
-        D = _decode_matrix_cached(self.k, self.m, self.kind,
-                                  tuple(present), tuple(targets))
-        chosen = np.stack([np.asarray(shards[i], dtype=np.uint8)
-                           for i in present[:self.k]], axis=-2)
-        if self.backend in ("numpy", "native"):
-            raw = self._matmul_begin(D, len(targets), chosen)
-        else:
-            raw = self._matmul_begin(rs_matrix.bit_matrix(D), len(targets),
-                                     chosen)
+        with self._stage("pack", "reconstruct"):
+            D = _decode_matrix_cached(self.k, self.m, self.kind,
+                                      tuple(present), tuple(targets))
+            chosen = np.stack([np.asarray(shards[i], dtype=np.uint8)
+                               for i in present[:self.k]], axis=-2)
+            if self.backend not in _CPU_BACKENDS:
+                D = rs_matrix.bit_matrix(D)
+            raw = self._matmul_begin(D, len(targets), chosen,
+                                     "reconstruct")
 
         def fetch():
             rec = raw()
-            out = list(shards)
-            for row, t in enumerate(targets):
-                out[t] = np.ascontiguousarray(rec[..., row, :])
-            return out
+            with self._stage("unpack", "reconstruct"):
+                out = list(shards)
+                for row, t in enumerate(targets):
+                    out[t] = np.ascontiguousarray(rec[..., row, :])
+                return out
         volumes = int(np.prod(chosen.shape[:-2], dtype=np.int64)) \
             if chosen.ndim > 2 else 1
         return metered_fetch(fetch, f"rs_{self.backend}", "reconstruct",

@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import functools
 import time as _time
+from contextlib import nullcontext
 
 import jax
 import jax.numpy as jnp
@@ -148,28 +149,34 @@ class MeshCodec:
         """Issue the mesh encode asynchronously; returns fetch() -> parity.
         Same contract as RSCodec.encode_begin — the seam the pipelined disk
         paths use to overlap IO with device compute."""
-        from ..ops.codec import metered_fetch
+        from ..ops.codec import device_stage, metered_fetch
         t0 = _time.perf_counter()
-        data = np.asarray(data, dtype=np.uint8)
-        assert data.shape[-2] == self.k, f"expected {self.k} data shards"
-        lead = data.shape[:-2]
-        volumes = int(np.prod(lead, dtype=np.int64)) if lead else 1
-        if lead:
-            # [.., k, B] -> [k, prod(lead)*B] keeping each stripe contiguous
-            flat = np.ascontiguousarray(
-                np.moveaxis(data, -2, 0)).reshape(self.k, -1)
-        else:
-            flat = data
-        inner = _mesh_matmul_begin(self.mesh, self._parity_bits, self.m,
-                                   flat)
+        stage = functools.partial(device_stage, backend="rs_mesh",
+                                  op="encode")
+        with stage("pack"):
+            data = np.asarray(data, dtype=np.uint8)
+            assert data.shape[-2] == self.k, \
+                f"expected {self.k} data shards"
+            lead = data.shape[:-2]
+            volumes = int(np.prod(lead, dtype=np.int64)) if lead else 1
+            if lead:
+                # [.., k, B] -> [k, prod(lead)*B] keeping each stripe
+                # contiguous
+                flat = np.ascontiguousarray(
+                    np.moveaxis(data, -2, 0)).reshape(self.k, -1)
+            else:
+                flat = data
+            inner = _mesh_matmul_begin(self.mesh, self._parity_bits,
+                                       self.m, flat, stage)
         if not lead:
             return metered_fetch(inner, "rs_mesh", "encode", data.nbytes,
                                  t0)
 
         def fetch():
             parity = inner()
-            return np.ascontiguousarray(np.moveaxis(
-                parity.reshape(self.m, *lead, -1), 0, -2))
+            with stage("unpack"):
+                return np.ascontiguousarray(np.moveaxis(
+                    parity.reshape(self.m, *lead, -1), 0, -2))
         return metered_fetch(fetch, "rs_mesh", "encode", data.nbytes, t0,
                              volumes=volumes)
 
@@ -188,7 +195,7 @@ class MeshCodec:
         """Async form of reconstruct: every per-chunk device call is issued
         before returning; fetch() drains them (RSCodec.encode_begin
         contract)."""
-        from ..ops.codec import metered_fetch
+        from ..ops.codec import device_stage, metered_fetch
         t0 = _time.perf_counter()
         if len(shards) != self.n:
             raise ValueError(f"expected {self.n} shard slots, got {len(shards)}")
@@ -201,35 +208,45 @@ class MeshCodec:
         if not targets:
             res = list(shards)
             return lambda: res
-        chosen = np.stack([np.asarray(shards[i], dtype=np.uint8)
-                           for i in present[:self.k]], axis=0)
-        if chosen.ndim not in (2, 3):
-            raise ValueError(
-                "MeshCodec.reconstruct expects [B] or [V, B] shards")
-        lead = chosen.shape[1:-1]  # () or (V,)
-        flat = chosen.reshape(self.k, -1)  # per-volume bytes stay contiguous
-        fn, k_pad = _recon_fn(self.mesh, self.k, self.m)
-        full = np.zeros((k_pad, flat.shape[-1]), dtype=np.uint8)
-        full[:self.k] = flat
-        padded, b = self._pad_cols(full, self._rec_mult)
-        dev_shards = jnp.asarray(padded.reshape(k_pad, 8, -1))  # free view
-        present_key = tuple(present[:self.k])
-        # the cached executable produces m rows per call; chunk wider
-        # target lists (possible for data_only bulk decodes of wide stripes)
-        pending = []
-        for i in range(0, len(targets), self.m):
-            chunk = targets[i:i + self.m]
-            dec_bits = jnp.asarray(_decode_bits_cached(
-                self.k, self.m, self.kind, k_pad, present_key, tuple(chunk)))
-            pending.append((chunk, fn(dec_bits, dev_shards)))
+        stage = functools.partial(device_stage, backend="rs_mesh",
+                                  op="reconstruct")
+        with stage("pack"):
+            chosen = np.stack([np.asarray(shards[i], dtype=np.uint8)
+                               for i in present[:self.k]], axis=0)
+            if chosen.ndim not in (2, 3):
+                raise ValueError(
+                    "MeshCodec.reconstruct expects [B] or [V, B] shards")
+            lead = chosen.shape[1:-1]  # () or (V,)
+            # per-volume bytes stay contiguous
+            flat = chosen.reshape(self.k, -1)
+            fn, k_pad = _recon_fn(self.mesh, self.k, self.m)
+            full = np.zeros((k_pad, flat.shape[-1]), dtype=np.uint8)
+            full[:self.k] = flat
+            padded, b = self._pad_cols(full, self._rec_mult)
+            # free view
+            dev_shards = jnp.asarray(padded.reshape(k_pad, 8, -1))
+            present_key = tuple(present[:self.k])
+            # the cached executable produces m rows per call; chunk wider
+            # target lists (possible for data_only bulk decodes of wide
+            # stripes)
+            pending = []
+            for i in range(0, len(targets), self.m):
+                chunk = targets[i:i + self.m]
+                dec_bits = jnp.asarray(_decode_bits_cached(
+                    self.k, self.m, self.kind, k_pad, present_key,
+                    tuple(chunk)))
+                pending.append((chunk, fn(dec_bits, dev_shards)))
 
         def fetch():
             out = list(shards)
             for chunk, dev in pending:
-                rec = np.asarray(jax.device_get(dev))
-                rec = rec.reshape(self.m, -1)[:, :b]
-                for row, t in enumerate(chunk):
-                    out[t] = np.ascontiguousarray(rec[row].reshape(*lead, -1))
+                with stage("wait"):
+                    rec = np.asarray(jax.device_get(dev))
+                with stage("unpack"):
+                    rec = rec.reshape(self.m, -1)[:, :b]
+                    for row, t in enumerate(chunk):
+                        out[t] = np.ascontiguousarray(
+                            rec[row].reshape(*lead, -1))
             return out
         volumes = int(np.prod(lead, dtype=np.int64)) if lead else 1
         return metered_fetch(fetch, "rs_mesh", "reconstruct",
@@ -284,10 +301,13 @@ def clay_mesh_encode_begin(k: int, m: int, data: np.ndarray, small: int,
     return fetch
 
 
-def _mesh_matmul_begin(mesh: Mesh, bits_dev, mo: int, flat: np.ndarray):
+def _mesh_matmul_begin(mesh: Mesh, bits_dev, mo: int, flat: np.ndarray,
+                       stage=None):
     """Shared core of every mesh byte-DP encode (MeshCodec RS parity and
     the generic/LRC matrix path): pad to the mesh's local block multiple,
-    dense shard-major relayout, dispatch, deferred fetch+strip."""
+    dense shard-major relayout, dispatch, deferred fetch+strip.
+    `stage(name)`, when given, times fetch's wait and unpack (MeshCodec
+    passes its ops.codec.device_stage)."""
     mult = sharded_codec.local_block_multiple(mesh, ("s", "b"))
     ki = flat.shape[0]
     b = flat.shape[-1]
@@ -296,10 +316,13 @@ def _mesh_matmul_begin(mesh: Mesh, bits_dev, mo: int, flat: np.ndarray):
         flat = np.pad(flat, ((0, 0), (0, pad)))
     sm = flat.reshape(ki, 8, -1)   # free host view -> dense tiling
     out = _encode_fn(mesh)(bits_dev, jnp.asarray(sm))
+    stage = stage or (lambda name: nullcontext())
 
     def fetch():
-        parity = np.asarray(jax.device_get(out)).reshape(mo, -1)[:, :b]
-        return np.ascontiguousarray(parity)
+        with stage("wait"):
+            host = np.asarray(jax.device_get(out))
+        with stage("unpack"):
+            return np.ascontiguousarray(host.reshape(mo, -1)[:, :b])
     return fetch
 
 

@@ -15,7 +15,10 @@ Tracing: every outgoing call attaches the ambient trace id as
 `x-trace-id` metadata (util/tracing.py); the server wrappers adopt it
 for the handler's duration, so a filer request's master Assign carries
 the same trace id as the originating HTTP hop.  Attaching a Tracer to
-`RpcServer.tracer` records one span per handled method.
+`RpcServer.tracer` records one span per handled method, with the stage
+tags its handler summed: `frame_s` (base64 and JSON framing, which runs
+on the thread that handles or consumes the messages) and, on a stream
+a client consumes, `recv_s` (blocked waiting for the next message).
 """
 
 from __future__ import annotations
@@ -72,19 +75,26 @@ def _server_credentials():
 
 
 def to_b64(raw: bytes) -> str:
-    return base64.b64encode(raw).decode("ascii")
+    with tracing.stage("frame"):
+        return base64.b64encode(raw).decode("ascii")
 
 
 def from_b64(s: str) -> bytes:
-    return base64.b64decode(s)
+    with tracing.stage("frame"):
+        return base64.b64decode(s)
 
 
 def _ser(d: dict) -> bytes:
-    return json.dumps(d, separators=(",", ":")).encode()
+    # grpc serializes a streamed response on the handler thread right
+    # after the handler's generator yields it, inside the handler's
+    # trace scope, so this lands on the streaming RPC's own span
+    with tracing.stage("frame"):
+        return json.dumps(d, separators=(",", ":")).encode()
 
 
 def _de(b: bytes) -> dict:
-    return json.loads(b) if b else {}
+    with tracing.stage("frame"):
+        return json.loads(b) if b else {}
 
 
 def _trace_metadata() -> "list[tuple[str, str]] | None":
@@ -151,15 +161,18 @@ class RpcServer:
 
     def _record(self, label: str, tid: str, t0: float, p0: float,
                 status: str, slow_log: bool = True, span_id: str = "",
-                parent_id: str = "") -> None:
+                parent_id: str = "", tags: "dict | None" = None
+                ) -> None:
         """`t0` is the wall-clock span START (cross-server alignment);
         `p0` the perf-counter twin the DURATION derives from — wall
-        deltas bend under NTP (weedlint WL120)."""
+        deltas bend under NTP (weedlint WL120).  `tags`: the stage tags
+        the handler summed (tracing.stage / tracing.add)."""
         tracer = self.tracer  # attached after construction; read late
         if tracer is not None:
             tracer.record(label, tid, t0, time.perf_counter() - p0,
                           status=status, slow_log=slow_log,
-                          span_id=span_id, parent_id=parent_id)
+                          span_id=span_id, parent_id=parent_id,
+                          **tracing.copy_tags(tags))
 
     def _wrap_unary(self, fn, label: str):
         def h(request: dict, context) -> dict:
@@ -170,6 +183,7 @@ class RpcServer:
                 tid, parent = _incoming_trace_ids(context)
                 tid = tid or tracing.new_trace_id()
                 sid = tracing.new_span_id()
+                tags: dict = {}
                 t0 = time.time()
                 p0 = time.perf_counter()
             status = "ok"
@@ -186,7 +200,7 @@ class RpcServer:
                             f"{label}")
                 if not traced:
                     return fn(request) or {}
-                with tracing.trace_scope(tid, sid):
+                with tracing.trace_scope(tid, sid, tags):
                     return fn(request) or {}
             except RpcError as e:
                 status = "error"
@@ -198,7 +212,8 @@ class RpcServer:
             finally:
                 if traced:
                     self._record(label, tid, t0, p0, status,
-                                 span_id=sid, parent_id=parent)
+                                 span_id=sid, parent_id=parent,
+                                 tags=tags)
         return h
 
     def _wrap_stream(self, fn, label: str):
@@ -208,6 +223,7 @@ class RpcServer:
                 tid, parent = _incoming_trace_ids(context)
                 tid = tid or tracing.new_trace_id()
                 sid = tracing.new_span_id()
+                tags: dict = {}
                 t0 = time.time()
                 p0 = time.perf_counter()
             status = "ok"
@@ -236,7 +252,7 @@ class RpcServer:
                 if not traced:
                     yield from faulted()
                     return
-                with tracing.trace_scope(tid, sid):
+                with tracing.trace_scope(tid, sid, tags):
                     yield from faulted()
             except RpcError as e:
                 status = "error"
@@ -253,7 +269,7 @@ class RpcServer:
                 if traced:
                     self._record(label, tid, t0, p0, status,
                                  slow_log=False, span_id=sid,
-                                 parent_id=parent)
+                                 parent_id=parent, tags=tags)
         return h
 
     def start(self) -> int:
@@ -351,15 +367,23 @@ class RpcClient:
         # long-lived SubscribeMetadata stream must actually die
         if faults.ACTIVE:
             self._maybe_fault(method)
+        # responses arrive as bytes and are parsed here, on the consuming
+        # thread (grpc would parse them on its channel thread), so the
+        # consumer's span sees the wait and the parse apart
         fn = self._channel.stream_stream(
             f"/{self.service}/{method}",
-            request_serializer=_ser, response_deserializer=_de)
+            request_serializer=_ser, response_deserializer=None)
         try:
-            for msg in fn(requests, timeout=timeout,
-                          metadata=_trace_metadata()):
+            responses = fn(requests, timeout=timeout,
+                           metadata=_trace_metadata())
+            while True:
+                with tracing.stage("recv"):
+                    raw = next(responses, None)
+                if raw is None:
+                    return
                 if faults.ACTIVE:
                     self._maybe_fault(method)
-                yield msg
+                yield _de(raw)
         except grpc.RpcError as e:
             raise RpcError(e.details() or str(e.code())) from None
 

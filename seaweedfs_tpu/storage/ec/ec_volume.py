@@ -12,6 +12,15 @@ ec_shard.go:17-93 and the read/recover path of weed/storage/store_ec.go:
   local shard when present, a remote shard via the pluggable `remote_reader`,
   or — degraded path — reconstructed on the fly from >= k other shards in
   ONE batched codec call (store_ec.go:125-382, recoverOneRemoteEcShardInterval).
+
+A needle read is timed in stages (util/tracing.stage), summed into the
+serving request's span tags:
+- `ec.locate_s`: the .ecx search;
+- `ec.interval.local_s`, `ec.interval.remote_s`: shard-interval reads;
+- `ec.reconstruct_s`: the codec call;
+- `needle.parse_s`.
+A reconstruct's survivor reads also feed the codec registry's `gather`
+series.
 """
 
 from __future__ import annotations
@@ -23,7 +32,8 @@ from typing import Callable
 
 import numpy as np
 
-from ...ops.codec import RSCodec
+from ...ops.codec import RSCodec, codec_stage, metrics_backend
+from ...util import tracing
 from .. import types as t
 from ..idx import parse_index_bytes
 from ..needle import Needle
@@ -226,9 +236,12 @@ class EcVolume:
                               ) -> "bytes | None":
         shard = self.shards.get(shard_id)
         if shard is not None:
-            return shard.read_at(size, offset)
+            with tracing.stage("ec.interval.local"):
+                return shard.read_at(size, offset)
         if self.remote_reader is not None:
-            return self.remote_reader(self.volume_id, shard_id, offset, size)
+            with tracing.stage("ec.interval.remote"):
+                return self.remote_reader(self.volume_id, shard_id, offset,
+                                          size)
         return None
 
     def _reconstruct_interval(self, missing_shard: int, offset: int,
@@ -251,18 +264,21 @@ class EcVolume:
         n = self.geo.total_shards
         shards: list[np.ndarray | None] = [None] * n
         got = 0
-        for sid in range(n):
-            if sid == missing_shard or got >= self.geo.data_shards:
-                continue
-            raw = self._read_local_or_remote(sid, offset, size)
-            if raw is not None and len(raw) == size:
-                shards[sid] = np.frombuffer(raw, dtype=np.uint8)
-                got += 1
+        with codec_stage("gather", metrics_backend(self.codec),
+                         "reconstruct"):
+            for sid in range(n):
+                if sid == missing_shard or got >= self.geo.data_shards:
+                    continue
+                raw = self._read_local_or_remote(sid, offset, size)
+                if raw is not None and len(raw) == size:
+                    shards[sid] = np.frombuffer(raw, dtype=np.uint8)
+                    got += 1
         if got < self.geo.data_shards:
             raise EcShardUnavailableError(
                 f"vol {self.volume_id} shard {missing_shard}: only {got} "
                 f"shards reachable, need {self.geo.data_shards}")
-        return self.codec.reconstruct(shards)[missing_shard].tobytes()
+        with tracing.stage("ec.reconstruct"):
+            return self.codec.reconstruct(shards)[missing_shard].tobytes()
 
     def _reconstruct_interval_lrc(self, missing_shard: int, offset: int,
                                   size: int) -> bytes:
@@ -353,10 +369,12 @@ class EcVolume:
     def read_needle(self, needle_id: int, cookie: "int | None" = None
                     ) -> Needle:
         """Full EC needle read (ReadEcShardNeedle store_ec.go:125-186)."""
-        _, size, intervals = self.locate_ec_shard_needle(needle_id)
+        with tracing.stage("ec.locate"):
+            _, size, intervals = self.locate_ec_shard_needle(needle_id)
         raw = b"".join(self.read_interval(iv) for iv in intervals)
         n = Needle()
-        n.read_bytes(raw, 0, size, self.version)
+        with tracing.stage("needle.parse"):
+            n.read_bytes(raw, 0, size, self.version)
         if cookie is not None and n.cookie != cookie:
             raise EcNotFoundError(f"cookie mismatch for {needle_id:x}")
         return n

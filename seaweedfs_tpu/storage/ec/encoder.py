@@ -28,7 +28,8 @@ import threading
 
 import numpy as np
 
-from ...ops.codec import RSCodec
+from ...ops.codec import RSCodec, codec_stage, metrics_backend
+from ...util import tracing
 from ..idx import index_array_to_bytes, parse_index_bytes
 from ..types import TOMBSTONE_FILE_SIZE
 from .layout import DEFAULT_GEOMETRY, EcGeometry, to_ext
@@ -130,7 +131,10 @@ def _pipelined(produce, consume, depth: int = PIPELINE_DEPTH) -> None:
             # after an error keep draining so the producer never deadlocks
             # on a full queue
 
-    t = threading.Thread(target=writer, name="ec-writer")
+    # the writer's fetch waits and file writes belong to the caller's
+    # span (the VolumeEcShardsGenerate RPC's stage tags)
+    t = threading.Thread(target=tracing.propagate(writer),
+                         name="ec-writer")
     t.start()
     try:
         for item in produce:
@@ -246,8 +250,12 @@ def write_ec_files(base_path: str, geo: EcGeometry = DEFAULT_GEOMETRY,
     Pipelined: the calling thread reads batch N+1 from .dat and submits its
     encode while the device computes batch N and a writer thread appends
     batch N-1's shards — disk in, TPU, disk out all busy at once (the
-    reference's encodeDatFile loop is strictly serial, ec_encoder.go:162)."""
+    reference's encodeDatFile loop is strictly serial, ec_encoder.go:162).
+    The codec registry times each stage under the codec's backend label:
+    `gather` (the .dat copy into the batch buffer), the codec call's own
+    pack/wait/unpack, and `write` (the shard files)."""
     codec = _codec_for(geo, codec)
+    backend = metrics_backend(codec)
     dat_size = os.path.getsize(base_path + ".dat")
     dat = np.memmap(base_path + ".dat", dtype=np.uint8, mode="r") \
         if dat_size else np.zeros(0, dtype=np.uint8)
@@ -256,16 +264,23 @@ def write_ec_files(base_path: str, geo: EcGeometry = DEFAULT_GEOMETRY,
     k = geo.data_shards
 
     def produce():
-        for data in _iter_encode_batches(dat, dat_size, geo, batch_bytes):
+        batches = _iter_encode_batches(dat, dat_size, geo, batch_bytes)
+        while True:
+            with codec_stage("gather", backend, "encode"):
+                data = next(batches, None)
+            if data is None:
+                return
             yield data, _begin_encode(codec, data)
 
     def consume(item):
         data, fetch = item
-        for s in range(k):
-            outputs[s].write(data[s])
+        with codec_stage("write", backend, "encode"):
+            for s in range(k):
+                outputs[s].write(data[s])
         parity = fetch()
-        for p in range(geo.parity_shards):
-            outputs[k + p].write(parity[p])
+        with codec_stage("write", backend, "encode"):
+            for p in range(geo.parity_shards):
+                outputs[k + p].write(parity[p])
 
     try:
         _pipelined(produce(), consume, _pipeline_depth(codec))

@@ -977,7 +977,8 @@ class HttpServer:
         parent = tracing.clamp_id(req.headers.get(tracing.SPAN_HEADER,
                                                   ""))
         sid = tracing.new_span_id()
-        with tracing.trace_scope(tid, sid):
+        tags: dict = {}
+        with tracing.trace_scope(tid, sid, tags):
             if handler is None:
                 resp = Response.error("not found", 404)
             else:
@@ -996,7 +997,8 @@ class HttpServer:
                           t0, time.perf_counter() - p0,
                           status=("ok" if resp.status < 400
                                   else f"http {resp.status}"),
-                          span_id=sid, parent_id=parent)
+                          span_id=sid, parent_id=parent,
+                          **tracing.copy_tags(tags))
         return resp
 
     def _serve_fault(self, conn, req: Request, resp: Response) -> bool:

@@ -31,14 +31,26 @@ The raw-TCP fast path carries the trace in the extended 'X' frame's
 optional trace slot (volume_server/tcp.py) — the former "deliberate gap"
 is closed: frame hops appear as real child spans.
 
-`WEED_TRACE=0` (or `set_enabled(False)`) turns span recording and
-propagation off process-wide — the knob the bench uses to price the
-observability tax (`tracing_overhead_pct`).
+Inside a span, `stage(name)` times one step of the work (a disk read,
+the base64/JSON framing of a message, a codec call's host-to-device
+pack) and sums its seconds into the span's `<name>_s` tag; `add(key,
+n)` sums a count (`bytes`).  The tags ride the open span's thread-local
+context, so work handed on through `propagate()` adds to the span that
+handed it on, and they are recorded with the span: /debug/traces shows
+where a slow `VolumeEcShardsCopy` spent its time.  When JAX is loaded in
+the process each stage is also a `jax.profiler.TraceAnnotation` named
+`weed.<name>`, so a profile taken with `/debug/profile` shows the
+stages on the device trace's own clock.
+
+`WEED_TRACE=0` (or `set_enabled(False)`) turns span recording, stage
+tags and propagation off process-wide.  Running the same load with
+`WEED_TRACE=1` and `WEED_TRACE=0` prices what tracing costs.
 """
 
 from __future__ import annotations
 
 import os
+import sys
 import threading
 from seaweedfs_tpu.util import locks
 import time
@@ -59,8 +71,8 @@ _ENABLED = os.environ.get("WEED_TRACE", "1") != "0"
 
 
 def enabled() -> bool:
-    """Process-wide tracing switch (WEED_TRACE env; bench flips it via
-    set_enabled to measure the observability tax in one process)."""
+    """Process-wide tracing switch (WEED_TRACE env, or set_enabled to
+    flip it in a running process)."""
     return _ENABLED
 
 
@@ -121,21 +133,106 @@ def current_span_id() -> str:
 
 
 @contextmanager
-def trace_scope(trace_id: str, span_id: str = ""):
+def trace_scope(trace_id: str, span_id: str = "",
+                tags: "dict | None" = None):
     """Install `trace_id` (and optionally `span_id`) as the thread's
     ambient trace for the block — outgoing HTTP/gRPC/frame calls inside
-    it propagate both.  Nests: the previous ids are restored on exit, so
-    a handler serving request B on a thread that still owns request A's
-    suspended stream is labeled B only for its own duration."""
+    it propagate both.  `tags` is the open span's tag dict, which
+    `stage()` and `add()` sum into (None: no span collects them).
+    Nests: the previous context is restored on exit, so a handler
+    serving request B on a thread that still owns request A's suspended
+    stream is labeled B only for its own duration."""
     prev_t = getattr(_ctx, "trace_id", "")
     prev_s = getattr(_ctx, "span_id", "")
+    prev_tags = getattr(_ctx, "tags", None)
     _ctx.trace_id = trace_id
     _ctx.span_id = span_id
+    _ctx.tags = tags
     try:
         yield trace_id
     finally:
         _ctx.trace_id = prev_t
         _ctx.span_id = prev_s
+        _ctx.tags = prev_tags
+
+
+# stage() and add() may sum into one span's tags from several threads
+# (the encoder's writer thread, replica fan-out workers); a read-modify-
+# write of a dict entry is not atomic under the GIL
+_TAGS_LOCK = threading.Lock()
+
+
+def add(key: str, value: float) -> None:
+    """Sum `value` into tag `key` of the thread's open span (no-op
+    outside a span, or with tracing off)."""
+    if not _ENABLED:
+        return
+    tags = getattr(_ctx, "tags", None)
+    if tags is not None:
+        with _TAGS_LOCK:
+            tags[key] = tags.get(key, 0) + value
+
+
+def copy_tags(tags: "dict | None") -> dict:
+    """A closing span's tags, copied under the lock stage()/add() sum
+    under (a propagated task may still be adding)."""
+    if not tags:
+        return {}
+    with _TAGS_LOCK:
+        return dict(tags)
+
+
+_annotation_cls = None
+
+
+def _annotation():
+    """jax.profiler.TraceAnnotation once JAX is loaded in the process;
+    None before (this module never imports JAX: the master, filer and
+    gateways load it too)."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        prof = sys.modules.get("jax.profiler")
+        if prof is None:
+            return None
+        _annotation_cls = prof.TraceAnnotation
+    return _annotation_cls
+
+
+class stage:
+    """`with stage("read"):` — one step of the work inside a span.
+
+    On exit its wall time is summed into the open span's `read_s` tag
+    and, when given, passed to `observe(seconds)` (a metrics counter
+    that counts whether or not tracing is on).  While JAX is loaded the
+    block is also a profiler annotation named `weed.read`.  With tracing
+    off and no `observe`, it costs a flag check and the annotation."""
+
+    __slots__ = ("name", "observe", "_ann", "_t0")
+
+    def __init__(self, name: str, observe=None):
+        self.name = name
+        self.observe = observe
+        self._ann = None
+        self._t0 = 0.0
+
+    def __enter__(self) -> "stage":
+        cls = _annotation()
+        if cls is not None:
+            self._ann = cls("weed." + self.name)
+            self._ann.__enter__()
+        if _ENABLED or self.observe is not None:
+            self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if not self._t0:
+            return
+        dt = time.perf_counter() - self._t0
+        if self.observe is not None:
+            self.observe(dt)
+        add(self.name + "_s", dt)
 
 
 def propagate(fn):
@@ -148,11 +245,12 @@ def propagate(fn):
     submit), installation at call time (the worker)."""
     tid = current_trace_id()
     sid = current_span_id()
+    tags = getattr(_ctx, "tags", None)
     if not tid:
         return fn
 
     def wrapped(*args, **kwargs):
-        with trace_scope(tid, sid):
+        with trace_scope(tid, sid, tags):
             return fn(*args, **kwargs)
     return wrapped
 
@@ -212,16 +310,17 @@ class Tracer:
         # it); the DURATION is monotonic — NTP must not bend a span
         t0 = time.time()
         p0 = time.perf_counter()
-        with trace_scope(tid, sid):
+        tags: dict = {}
+        with trace_scope(tid, sid, tags):
             try:
                 yield tid
             except BaseException:
                 self.record(name, tid, t0, time.perf_counter() - p0,
                             status="error", span_id=sid,
-                            parent_id=parent)
+                            parent_id=parent, **copy_tags(tags))
                 raise
         self.record(name, tid, t0, time.perf_counter() - p0,
-                    span_id=sid, parent_id=parent)
+                    span_id=sid, parent_id=parent, **copy_tags(tags))
 
     def snapshot(self, trace_id: str = "", limit: int = 0,
                  min_ms: float = 0.0) -> list[dict]:

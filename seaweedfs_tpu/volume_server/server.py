@@ -1699,7 +1699,10 @@ class VolumeServer:
 
     def _rpc_ec_copy(self, req: dict) -> dict:
         """Copy shard files from the source server via CopyFile streams
-        (volume_grpc_erasure_coding.go:117-180)."""
+        (volume_grpc_erasure_coding.go:117-180).  The span's tags split
+        its time: `recv_s` (waiting on the stream), `frame_s` (JSON and
+        base64 decode), `write_s` (file writes and the final renames)
+        and `bytes` received."""
         vid = int(req["volume_id"])
         collection = req.get("collection", "")
         base = self._base_path(vid, collection)
@@ -1716,14 +1719,18 @@ class VolumeServer:
                     for r in src.stream("CopyFile", iter([{
                             "volume_id": vid, "collection": collection,
                             "ext": ext}])):
-                        f.write(from_b64(r["file_content"]))
+                        data = from_b64(r["file_content"])
+                        with tracing.stage("write"):
+                            f.write(data)
+                        tracing.add("bytes", len(data))
             except RpcError:
                 if os.path.exists(tmp):
                     os.remove(tmp)
                 if ext == ".ecj":  # journal may not exist yet
                     continue
                 raise
-            os.replace(tmp, base + ext)
+            with tracing.stage("write"):
+                os.replace(tmp, base + ext)
         return {}
 
     def _rpc_ec_delete(self, req: dict) -> dict:
@@ -1829,7 +1836,10 @@ class VolumeServer:
         raise NotFoundError(f"volume {fid.volume_id} not found")
 
     def _rpc_copy_file(self, requests):
-        """Stream any volume/shard file (CopyFile volume_server.proto:60)."""
+        """Stream any volume/shard file (CopyFile volume_server.proto:60).
+        The span's tags split its time: `read_s` (disk), `frame_s`
+        (base64, and the JSON of each message, serialized on this
+        thread) and `bytes` sent."""
         for req in requests:
             base = self._base_path(int(req["volume_id"]),
                                    req.get("collection", ""))
@@ -1838,7 +1848,9 @@ class VolumeServer:
                 raise RpcError(f"{path} not found")
             with open(path, "rb") as f:
                 while True:
-                    chunk = f.read(1 << 20)
+                    with tracing.stage("read"):
+                        chunk = f.read(1 << 20)
                     if not chunk:
                         break
+                    tracing.add("bytes", len(chunk))
                     yield {"file_content": to_b64(chunk)}
