@@ -2,10 +2,17 @@
 
 Capability-equivalent to the reference's generated stubs + connection cache
 (weed/pb/grpc_client_server.go): every service is a name -> handler map
-registered through grpc generic handlers; payloads are JSON dicts (bytes
-fields travel base64 via to_b64/from_b64).  Unary and bidi-streaming methods
-cover everything the reference's 6 protos use (heartbeat streams, shard
-copy streams, metadata subscribe streams).
+registered through grpc generic handlers; payloads are dicts.  A message
+whose top-level values are all JSON travels as that JSON.  A message with
+top-level `bytes`/`bytearray`/`memoryview` values (the shard chunks of
+CopyFile and VolumeEcShardRead, like the reference's protobuf `bytes`
+fields) travels as an envelope: a 0x00 tag (never the first byte of JSON),
+a 4-byte big-endian header length, a JSON header holding the other fields
+and each bytes field's name and length, then the payloads raw, in order;
+it arrives as a dict with `bytes` values.  Bytes nested deeper, such as KV
+values, travel base64 via to_b64/from_b64.  Unary and bidi-streaming
+methods cover everything the reference's 6 protos use (heartbeat streams,
+shard copy streams, metadata subscribe streams).
 
 Error convention: a handler raising RpcError(msg) (or any Exception) aborts
 the call with the message in the gRPC status details; clients re-raise it
@@ -16,9 +23,10 @@ Tracing: every outgoing call attaches the ambient trace id as
 for the handler's duration, so a filer request's master Assign carries
 the same trace id as the originating HTTP hop.  Attaching a Tracer to
 `RpcServer.tracer` records one span per handled method, with the stage
-tags its handler summed: `frame_s` (base64 and JSON framing, which runs
-on the thread that handles or consumes the messages) and, on a stream
-a client consumes, `recv_s` (blocked waiting for the next message).
+tags its handler summed: `frame_s` (building or parsing messages, JSON
+or envelope, and any base64, on the thread that handles or consumes
+them), `raw_bytes` (payload bytes that went raw in an envelope) and, on a
+stream a client consumes, `recv_s` (blocked waiting for the next message).
 """
 
 from __future__ import annotations
@@ -84,17 +92,46 @@ def from_b64(s: str) -> bytes:
         return base64.b64decode(s)
 
 
+_RAW_TAG = b"\x00"
+_RAW_TYPES = (bytes, bytearray, memoryview)
+
+
 def _ser(d: dict) -> bytes:
     # grpc serializes a streamed response on the handler thread right
     # after the handler's generator yields it, inside the handler's
     # trace scope, so this lands on the streaming RPC's own span
     with tracing.stage("frame"):
-        return json.dumps(d, separators=(",", ":")).encode()
+        raw = [k for k, v in d.items() if isinstance(v, _RAW_TYPES)]
+        if not raw:
+            return json.dumps(d, separators=(",", ":")).encode()
+        views = [memoryview(d[k]) for k in raw]
+        sizes = [v.nbytes for v in views]
+        head = json.dumps(
+            {"fields": {k: v for k, v in d.items() if k not in raw},
+             "raw": [[k, n] for k, n in zip(raw, sizes)]},
+            separators=(",", ":")).encode()
+        tracing.add("raw_bytes", sum(sizes))
+        return b"".join([_RAW_TAG, len(head).to_bytes(4, "big"), head,
+                         *views])
 
 
 def _de(b: bytes) -> dict:
     with tracing.stage("frame"):
-        return json.loads(b) if b else {}
+        if not b:
+            return {}
+        if b[:1] != _RAW_TAG:
+            return json.loads(b)
+        at = 5 + int.from_bytes(b[1:5], "big")
+        head = json.loads(b[5:at])
+        out = head["fields"]
+        start = at
+        for name, size in head["raw"]:
+            out[name] = b[at:at + size]     # the one copy of the payload
+            at += size
+        if at != len(b):
+            raise ValueError(f"envelope of {len(b)} bytes declares {at}")
+        tracing.add("raw_bytes", at - start)
+        return out
 
 
 def _trace_metadata() -> "list[tuple[str, str]] | None":

@@ -1210,7 +1210,7 @@ class VolumeServer:
                     continue
                 try:
                     client = POOL.client(addr, "VolumeServer")
-                    chunks = [from_b64(r["data"]) for r in client.stream(
+                    chunks = [r["data"] for r in client.stream(
                         "VolumeEcShardRead",
                         iter([{"volume_id": vid2, "shard_id": shard_id,
                                "offset": offset, "size": size}]))]
@@ -1517,7 +1517,7 @@ class VolumeServer:
                     for r in src.stream("CopyFile", iter([{
                             "volume_id": vid, "collection": collection,
                             "ext": ext}])):
-                        f.write(from_b64(r["file_content"]))
+                        f.write(r["file_content"])
         except Exception:
             for ext in (".dat", ".idx"):
                 if os.path.exists(base + ext + ".tmp"):
@@ -1700,9 +1700,10 @@ class VolumeServer:
     def _rpc_ec_copy(self, req: dict) -> dict:
         """Copy shard files from the source server via CopyFile streams
         (volume_grpc_erasure_coding.go:117-180).  The span's tags split
-        its time: `recv_s` (waiting on the stream), `frame_s` (JSON and
-        base64 decode), `write_s` (file writes and the final renames)
-        and `bytes` received."""
+        its time: `recv_s` (waiting on the stream), `frame_s` (parsing
+        each message's envelope), `write_s` (file writes and the final
+        renames), `bytes` received and `raw_bytes`, the part of them that
+        came raw (all of it: the chunks are `bytes` values)."""
         vid = int(req["volume_id"])
         collection = req.get("collection", "")
         base = self._base_path(vid, collection)
@@ -1719,7 +1720,7 @@ class VolumeServer:
                     for r in src.stream("CopyFile", iter([{
                             "volume_id": vid, "collection": collection,
                             "ext": ext}])):
-                        data = from_b64(r["file_content"])
+                        data = r["file_content"]
                         with tracing.stage("write"):
                             f.write(data)
                         tracing.add("bytes", len(data))
@@ -1819,7 +1820,7 @@ class VolumeServer:
                 chunk = shard.read_at(min(remaining, 1 << 20), offset)
                 if not chunk:
                     break
-                yield {"data": to_b64(chunk)}
+                yield {"data": chunk}
                 offset += len(chunk)
                 remaining -= len(chunk)
 
@@ -1838,8 +1839,9 @@ class VolumeServer:
     def _rpc_copy_file(self, requests):
         """Stream any volume/shard file (CopyFile volume_server.proto:60).
         The span's tags split its time: `read_s` (disk), `frame_s`
-        (base64, and the JSON of each message, serialized on this
-        thread) and `bytes` sent."""
+        (building each message's envelope, serialized on this thread),
+        `bytes` sent and `raw_bytes`, the part of them sent raw (all of
+        it: each chunk is yielded as a `bytes` value)."""
         for req in requests:
             base = self._base_path(int(req["volume_id"]),
                                    req.get("collection", ""))
@@ -1853,4 +1855,4 @@ class VolumeServer:
                     if not chunk:
                         break
                     tracing.add("bytes", len(chunk))
-                    yield {"file_content": to_b64(chunk)}
+                    yield {"file_content": chunk}

@@ -245,6 +245,8 @@ def test_ec_encode_copy_spans_carry_bytes_and_stages(cluster):
             copies += 1
             for tag in ("recv_s", "frame_s", "write_s"):
                 assert 0 < s[tag] <= s["duration_ms"] / 1e3, (tag, s)
+            # every shard byte came raw, none base64 in JSON
+            assert s["raw_bytes"] == s["bytes"], s
         for s in spans:
             if s["name"] != "VolumeServer/CopyFile" or s["status"] != "ok":
                 continue
@@ -252,6 +254,7 @@ def test_ec_encode_copy_spans_carry_bytes_and_stages(cluster):
             sent += s["bytes"]
             for tag in ("read_s", "frame_s"):
                 assert 0 < s[tag] <= s["duration_ms"] / 1e3, (tag, s)
+            assert s["raw_bytes"] == s["bytes"], s
     assert copies >= 2 and sends >= copies
     assert sent == copied
 

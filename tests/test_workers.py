@@ -19,10 +19,12 @@ import os
 import pytest
 
 from seaweedfs_tpu import operation
+from seaweedfs_tpu.pb.rpc import POOL
 from seaweedfs_tpu.testing import SimCluster
 from seaweedfs_tpu.util.http import http_request
 from seaweedfs_tpu.volume_server import VolumeServer
-from seaweedfs_tpu.volume_server.workers import ShardedVolumeServer
+from seaweedfs_tpu.volume_server.workers import (ShardedVolumeServer,
+                                                 worker_partition_dir)
 
 
 @pytest.fixture(scope="module")
@@ -257,3 +259,34 @@ def test_workers_one_is_plain_volume_server():
         vs.store.close()
         for m in c.masters:
             m.stop()
+
+
+def test_bulk_streams_pass_raw_bytes_through_the_front(sharded):
+    """CopyFile and VolumeEcShardRead routed through the supervisor's
+    gRPC port re-yield the owning worker's messages: the raw bytes of
+    their envelopes arrive unchanged, as the files on disk hold them."""
+    c = sharded
+    vs = c.volume_servers[0]
+    fid = c.upload(os.urandom(300_000))
+    vid = int(fid.split(",")[0])
+    base = os.path.join(worker_partition_dir(vs.directories[0],
+                                             vs.owner_of(vid)), str(vid))
+    front = POOL.client(vs.rpc.address, "VolumeServer")
+    got = [r["file_content"] for r in front.stream("CopyFile", iter([{
+        "volume_id": vid, "collection": "", "ext": ".dat"}]))]
+    assert all(type(b) is bytes for b in got)
+    with open(base + ".dat", "rb") as f:
+        assert b"".join(got) == f.read()
+    # seal the volume on its worker, then read a shard back through the
+    # front (the last sharded test: the volume stays read-only)
+    front.call("VolumeEcShardsGenerate", {"volume_id": vid,
+                                          "collection": ""})
+    front.call("VolumeEcShardsMount", {"volume_id": vid, "collection": "",
+                                       "shard_ids": list(range(14))})
+    with open(base + ".ec03", "rb") as f:
+        shard = f.read()
+    got = [r["data"] for r in front.stream("VolumeEcShardRead", iter([{
+        "volume_id": vid, "shard_id": 3, "offset": 100,
+        "size": len(shard) - 100}]))]
+    assert all(type(b) is bytes for b in got)
+    assert b"".join(got) == shard[100:]
