@@ -259,10 +259,25 @@ def resolve_backend() -> str:
     return "native" if _native_codec_built() else "jax"
 
 
+def gf_apply_backend() -> str:
+    """The backend gf_apply(backend="auto") runs: the jax bit-plane
+    matmul on a device ('pallas' pins mean the device here), else the
+    native codec, numpy tables when the .so cannot build."""
+    override = ec_backend_override()
+    if override is not None:
+        validate_ec_backend_pin()
+        backend = "jax" if override in _DEVICE_BACKENDS else override
+    else:
+        backend = "jax" if _tpu_available() else "native"
+    if backend == "native" and not _native_codec_built():
+        return "numpy"
+    return backend
+
+
 def gf_apply(M: np.ndarray, x: np.ndarray, *,
              backend: str = "auto") -> np.ndarray:
     """out[MO, B] = M ∘GF∘ x[KI, B] for an ARBITRARY GF(2^8) matrix —
-    the executor behind the clay/LRC flat-matrix paths (storage/ec/codes.py).
+    the executor behind clay's flat-matrix paths (storage/ec/codes.py).
 
     TPU: the bit-plane MXU matmul (ops/rs_jax) — unlike the Pallas
     kernel, the [8MO, 8KI] bit matrix streams from HBM, so clay's
@@ -270,14 +285,7 @@ def gf_apply(M: np.ndarray, x: np.ndarray, *,
     native AVX2 codec, numpy tables as last resort.  Bytes are identical
     on every path."""
     if backend == "auto":
-        override = ec_backend_override()
-        if override is not None:
-            validate_ec_backend_pin()
-            # gf_apply's device path is the bit-plane XLA matmul; a
-            # 'pallas' pin means "use the device", which here is 'jax'
-            backend = "jax" if override in _DEVICE_BACKENDS else override
-        else:
-            backend = "jax" if _tpu_available() else "native"
+        backend = gf_apply_backend()
     if backend == "native":
         if _native_codec_built():
             from .. import native
@@ -340,15 +348,17 @@ class RSCodec:
             arr = np.pad(arr, [(0, 0)] * (arr.ndim - 1) + [(0, pad)])
         return arr, b
 
-    def _stage(self, stage: str, op: str):
-        """device_stage of this codec's calls; the CPU backends compute
-        inside the call and record no pack, wait or unpack."""
+    def _stage(self, stage: str, op: str, label: "str | None" = None):
+        """device_stage of this codec's calls, under `label` (default
+        rs_<backend>); the CPU backends compute inside the call and
+        record no pack, wait or unpack."""
         if self.backend in _CPU_BACKENDS:
             return nullcontext()
-        return device_stage(stage, f"rs_{self.backend}", op)
+        return device_stage(stage, label or f"rs_{self.backend}", op)
 
     def _matmul_begin(self, bits_shard_major: np.ndarray, mo: int,
-                      inputs: np.ndarray, op: str):
+                      inputs: np.ndarray, op: str,
+                      label: "str | None" = None):
         """Dispatch out = M ∘GF∘ inputs[..., KI, B] to the chosen backend.
 
         Returns a zero-arg fetch() -> np.ndarray.  On device backends the
@@ -356,7 +366,7 @@ class RSCodec:
         fetch() blocks on the result — the seam the pipelined disk paths in
         storage/ec/encoder.py use to overlap disk reads, device compute and
         shard-file writes.  fetch() times its `wait` and `unpack` stages
-        under `op`."""
+        under `op` and `label`."""
         squeeze = inputs.ndim == 2
         if squeeze:
             inputs = inputs[None]
@@ -389,9 +399,9 @@ class RSCodec:
                 interpret=self.interpret)
 
             def fetch():
-                with self._stage("wait", op):
+                with self._stage("wait", op, label):
                     host = np.asarray(jax.device_get(dev))
-                with self._stage("unpack", op):
+                with self._stage("unpack", op, label):
                     out = rs_pallas.from_sm_layout(host, lead, bp)
                     out = out[..., :b]
                     return out[0] if squeeze else out
@@ -400,9 +410,9 @@ class RSCodec:
             jnp.asarray(bits_shard_major), jnp.asarray(padded))
 
         def fetch():
-            with self._stage("wait", op):
+            with self._stage("wait", op, label):
                 host = np.asarray(jax.device_get(dev))
-            with self._stage("unpack", op):
+            with self._stage("unpack", op, label):
                 out = host[..., :b]
                 return out[0] if squeeze else out
         return fetch
@@ -419,6 +429,36 @@ class RSCodec:
         return self._parity_bits_dev
 
     # -- public API ------------------------------------------------------
+    def apply_begin(self, M: np.ndarray, inputs: np.ndarray, op: str, *,
+                    label: "str | None" = None,
+                    volumes: "int | None" = None):
+        """Issue out[..., MO, B] = M ∘GF∘ inputs[..., KI, B] for an
+        arbitrary GF(2^8) matrix M no larger than this codec's own
+        [m, k] parity block, on this codec's backend (pallas: the
+        shard-major kernel, tiled for that block).  Returns fetch(),
+        metered as `op` under `label` (default rs_<backend>), with the
+        pack, wait and unpack stages as encode_begin's.  LRC encode and
+        the RS and LRC rebuilds run their matrices through it.
+        `volumes` counts the volumes the call carries where they share
+        the byte axis (default: the leading batch axes)."""
+        t0 = time.perf_counter()
+        M = np.ascontiguousarray(M, dtype=np.uint8)
+        mo, ki = M.shape
+        if mo > self.m or ki > self.k:
+            raise ValueError(f"matrix [{mo}, {ki}] exceeds the codec's "
+                             f"[{self.m}, {self.k}]")
+        label = label or f"rs_{self.backend}"
+        with self._stage("pack", op, label):
+            inputs = np.asarray(inputs, dtype=np.uint8)
+            assert inputs.shape[-2] == ki, (inputs.shape, M.shape)
+            bits = M if self.backend in _CPU_BACKENDS \
+                else rs_matrix.bit_matrix(M)
+            fetch = self._matmul_begin(bits, mo, inputs, op, label)
+        if volumes is None:
+            volumes = int(np.prod(inputs.shape[:-2], dtype=np.int64))
+        return metered_fetch(fetch, label, op, inputs.nbytes, t0,
+                             volumes=volumes)
+
     def encode(self, data: np.ndarray) -> np.ndarray:
         """data [.., k, B] uint8 -> parity [.., m, B] uint8."""
         return self.encode_begin(data)()

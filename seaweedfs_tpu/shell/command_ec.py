@@ -13,7 +13,8 @@ import json
 import time
 
 from ..pb.rpc import RpcError
-from ..storage.ec.layout import TOTAL_SHARDS_COUNT
+from ..storage.ec.layout import TOTAL_SHARDS_COUNT, EcGeometry
+from ..storage.ec.plan import RepairPlan, repair_plan
 from ..storage.ec.shard_bits import ShardBits
 from ..util.weedlog import logger
 from .commands import (CommandEnv, ShellError, command, iter_data_nodes,
@@ -65,6 +66,31 @@ def plan_shard_distribution(topo: dict, vid: int, source_id: str,
     for shard in range(n_total):
         out[order[shard % len(order)]].append(shard)
     return {nid: shards for nid, shards in out.items() if shards}
+
+
+def plan_rebuild(geo: EcGeometry, missing: list[int],
+                 shard_map: dict[str, list[int]]
+                 ) -> tuple[str, RepairPlan, dict[str, list[int]]]:
+    """(rebuilder, its repair plan, {holder: shards it copies the
+    rebuilder}).  The rebuilder is the holder of the most of its plan's
+    read set (storage/ec/plan.py, shards it holds preferred), ties
+    broken by the most shards of the volume, then the lowest id; only
+    the part of the read set it lacks is copied.  Raises ValueError when
+    the survivors cannot rebuild `missing`."""
+    present = sorted({s for ids in shard_map.values() for s in ids})
+    plans = {nid: repair_plan(geo, missing, present, prefer=ids)
+             for nid, ids in shard_map.items()}
+    rebuilder = min(shard_map, key=lambda nid: (
+        -len(set(plans[nid].read_shards) & set(shard_map[nid])),
+        -len(shard_map[nid]), nid))
+    need = set(plans[rebuilder].read_shards) - set(shard_map[rebuilder])
+    copies: dict[str, list[int]] = {}
+    for nid, ids in shard_map.items():
+        take = [s for s in ids if s in need]
+        if nid != rebuilder and take:
+            copies[nid] = take
+            need -= set(take)
+    return rebuilder, plans[rebuilder], copies
 
 
 def collect_ec_shard_map(topo: dict) -> dict[int, dict[str, list[int]]]:
@@ -212,48 +238,60 @@ def _do_ec_encode_traced(env: CommandEnv, vid: int, tid: str,
     return {"volume_id": vid, "distribution": plan}
 
 
-def do_ec_rebuild(env: CommandEnv, vid: int, collection: str = "") -> dict:
-    """Pick a rebuilder, gather >=k shards on it, rebuild + mount the
-    missing ones (command_ec_rebuild.go:58-230)."""
-    topo = env.topology()
-    shard_map = collect_ec_shard_map(topo).get(vid, {})
-    present = {s for ids in shard_map.values() for s in ids}
-    grpc_by_id = {dn["id"]: node_grpc(dn)
-                  for _, _, dn in iter_data_nodes(topo)}
-    # wide stripes: the true total comes from a holder's .vif, not the
-    # fixed 10+4 default
-    n_total = TOTAL_SHARDS_COUNT
-    for nid in shard_map:
+def _ec_geometry(env: CommandEnv, vid: int, collection: str,
+                 holders: list[str], grpc_by_id: dict) -> EcGeometry:
+    """The stripe geometry and code of an EC volume, from a holder's .vif
+    (wide stripes and the clay/LRC families: not the fixed 10+4)."""
+    for nid in holders:
         try:
-            n_total = env.volume_server(grpc_by_id[nid]).call(
+            g = env.volume_server(grpc_by_id[nid]).call(
                 "VolumeEcGeometry",
-                {"volume_id": vid, "collection": collection}
-            )["total_shards"]
-            break
+                {"volume_id": vid, "collection": collection})
         except RpcError:
             continue
-    missing = [s for s in range(n_total) if s not in present]
+        return EcGeometry(data_shards=g["data_shards"],
+                          parity_shards=g["parity_shards"],
+                          code_kind=g.get("code_kind", "rs"),
+                          lrc_locals=g.get("lrc_locals", 0))
+    return EcGeometry()
+
+
+def do_ec_rebuild(env: CommandEnv, vid: int, collection: str = "") -> dict:
+    """Pick a rebuilder, copy it the part of the repair's read set it
+    lacks, rebuild + mount the missing shards (command_ec_rebuild.go:
+    58-230).
+
+    The read set comes from the planner the rebuilder's
+    VolumeEcShardsRebuild reads by (storage/ec/plan.py): k survivors for
+    RS, the d helpers for clay, the lost shard's local group for a
+    single LRC loss.  The rebuilder is the server holding the most of
+    that set (ties: the most shards of the volume, then the lowest id),
+    so the copy is as small as the placement allows; it regenerates only
+    the missing shards and then deletes its temporary copies."""
+    topo = env.topology()
+    shard_map = collect_ec_shard_map(topo).get(vid, {})
+    present = sorted({s for ids in shard_map.values() for s in ids})
+    grpc_by_id = {dn["id"]: node_grpc(dn)
+                  for _, _, dn in iter_data_nodes(topo)}
+    geo = _ec_geometry(env, vid, collection, list(shard_map), grpc_by_id)
+    missing = [s for s in range(geo.total_shards) if s not in present]
     if not missing:
-        return {"volume_id": vid, "rebuilt": []}
-    # rebuilder: most local shards already
-    rebuilder_id = max(shard_map, key=lambda nid: len(shard_map[nid]))
+        return {"volume_id": vid, "rebuilt": [], "copied": []}
+    try:
+        rebuilder_id, _, copies = plan_rebuild(geo, missing, shard_map)
+    except ValueError as e:
+        raise ShellError(f"ec volume {vid}: {e}") from None
     rebuilder = env.volume_server(grpc_by_id[rebuilder_id])
-    local = set(shard_map[rebuilder_id])
-    copied = []
-    for node_id, ids in shard_map.items():
-        if node_id == rebuilder_id:
-            continue
-        need = [s for s in ids if s not in local]
-        if need:
-            rebuilder.call("VolumeEcShardsCopy", {
-                "volume_id": vid, "collection": collection,
-                "shard_ids": need, "copy_ecx_files": False,
-                "source_data_node": grpc_by_id[node_id]}, timeout=3600)
-            local |= set(need)
-            copied += need
+    copied: list[int] = []
+    for node_id, take in copies.items():
+        rebuilder.call("VolumeEcShardsCopy", {
+            "volume_id": vid, "collection": collection,
+            "shard_ids": take, "copy_ecx_files": False,
+            "source_data_node": grpc_by_id[node_id]}, timeout=3600)
+        copied += take
     out = rebuilder.call("VolumeEcShardsRebuild",
-                         {"volume_id": vid, "collection": collection},
-                         timeout=3600)
+                         {"volume_id": vid, "collection": collection,
+                          "shard_ids": missing}, timeout=3600)
     rebuilt = out.get("rebuilt_shard_ids", [])
     rebuilder.call("VolumeEcShardsMount",
                    {"volume_id": vid, "collection": collection,
@@ -265,10 +303,10 @@ def do_ec_rebuild(env: CommandEnv, vid: int, collection: str = "") -> dict:
                        {"volume_id": vid, "collection": collection,
                         "shard_ids": stale})
     return {"volume_id": vid, "rebuilt": rebuilt,
-            "rebuilder": rebuilder_id,
-            # repair-IO accounting (bytes_read, plan_kind, helpers):
-            # operators see the clay/LRC reduced-read plans in the verb
-            # output, mirrored by the /metrics rebuild counters
+            "rebuilder": rebuilder_id, "copied": sorted(copied),
+            # repair-IO accounting (bytes_read, plan_kind, read_shards,
+            # executor): operators see the clay/LRC reduced-read plans
+            # in the verb output, mirrored by the /metrics counters
             "rebuild_stats": out.get("rebuild_stats", {})}
 
 

@@ -70,9 +70,14 @@ def encode_volume_to_ec(base_path: str, version: int,
 
     The exact .dat size goes into .vif: shard size alone cannot recover the
     large/small row split at row boundaries (layout.n_large_block_rows).
-    The geometry goes there too (wide-stripe volumes are self-describing)."""
+    The geometry goes there too (wide-stripe volumes are self-describing),
+    and for LRC the construction of its global rows (ops/lrc.py)."""
     write_sorted_file_from_idx(base_path)
     write_ec_files(base_path, geo, codec)
+    extra = {}
+    if geo.code_kind == "lrc":
+        from ...ops.lrc import CONSTRUCTION
+        extra["lrc_construction"] = CONSTRUCTION
     save_volume_info(base_path, version,
                      dat_size=os.path.getsize(base_path + ".dat"),
                      data_shards=geo.data_shards,
@@ -80,7 +85,7 @@ def encode_volume_to_ec(base_path: str, version: int,
                      large_block_size=geo.large_block_size,
                      small_block_size=geo.small_block_size,
                      code_kind=geo.code_kind,
-                     lrc_locals=geo.lrc_locals)
+                     lrc_locals=geo.lrc_locals, **extra)
 
 
 def decode_ec_to_volume(base_path: str,

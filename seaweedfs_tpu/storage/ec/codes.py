@@ -15,22 +15,25 @@ window — real partial-range file reads, the whole point of MSR codes
 (1/q the repair IO at identical storage overhead).
 
 Execution: the numpy oracles (ops/clay.py, ops/lrc.py) are matrix
-factories (ops/clay_matrix.py); the hot path is always one GF(2^8)
-matmul via ops.codec.gf_apply — bit-plane MXU on TPU, AVX2 native on
-CPU.  Same engine as RS, different matrices.
+factories (ops/clay_matrix.py).  LRC's matrices are as small as RS's and
+run on RS's own executor (RSCodec.apply_begin: the shard-major Pallas
+kernel on TPU, the native codec on CPU); its rebuild is RS's pipelined
+loop (encoder.rebuild_ec_files).  Clay's flat matrices are too large for
+that kernel and go through ops.codec.gf_apply, or its fused kernels.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 import time
 
 import numpy as np
 
 from ...ops import clay_matrix, lrc
-from ...ops.codec import codec_metrics, gf_apply, metered_fetch
+from ...ops.codec import (RSCodec, codec_metrics, gf_apply, gf_apply_backend,
+                          metered_fetch)
 from .layout import EcGeometry, to_ext
+from .plan import RepairPlan, lrc_geometry
 
 
 def window_codec_for(geo: EcGeometry):
@@ -42,13 +45,15 @@ def window_codec_for(geo: EcGeometry):
     raise ValueError(f"unknown code_kind {geo.code_kind!r}")
 
 
-def lrc_geometry(geo: EcGeometry) -> lrc.LrcGeometry:
-    if not geo.lrc_locals or geo.data_shards % geo.lrc_locals:
+def require_construction(volume: str, recorded: "str | None") -> None:
+    """Refuse an LRC volume whose .vif does not record the construction
+    of ops/lrc.py's global rows: its parities were computed with other
+    coefficients, and decoding them with these would write wrong bytes."""
+    if recorded != lrc.CONSTRUCTION:
         raise ValueError(
-            f"lrc needs lrc_locals dividing k: k={geo.data_shards} "
-            f"l={geo.lrc_locals}")
-    return lrc.LrcGeometry(k=geo.data_shards, l=geo.lrc_locals,
-                           r=geo.parity_shards - geo.lrc_locals)
+            f"LRC volume {volume}: .vif records lrc_construction "
+            f"{recorded!r}, not {lrc.CONSTRUCTION!r}; its global parities "
+            f"were sealed under other coefficients")
 
 
 def _multi_device() -> bool:
@@ -58,10 +63,11 @@ def _multi_device() -> bool:
 
 
 class LrcWindowCodec:
-    """LRC is scalar (per byte column) like RS — encode is one matmul;
-    the local-repair advantage lives entirely in the rebuild planner.
-    Multi-device hosts ride the mesh byte-DP path (VERDICT r3 weak #6:
-    all three code families scale over the chips, not just RS)."""
+    """LRC is scalar (per byte column) like RS — encode is one matmul,
+    issued on RS's executor and fetched later, so write_ec_files
+    pipelines it as it does RS; the local-repair advantage lives in the
+    rebuild planner.  Multi-device hosts ride the mesh byte-DP path
+    (all three code families scale over the chips, not just RS)."""
 
     def __init__(self, geo: EcGeometry):
         self.geo = geo
@@ -69,22 +75,26 @@ class LrcWindowCodec:
         self.k = geo.data_shards
         self.m = geo.parity_shards
         self.backend = "lrc"
+        self.parity_rows = np.ascontiguousarray(
+            lrc.generator_matrix(self.lgeo)[self.k:])
+        # single chip: RS's executor, whose apply_begin runs any matrix
+        # up to [m, k]
+        self.executor = None if _multi_device() \
+            else RSCodec(self.k, self.m)
 
     def encode(self, data: np.ndarray) -> np.ndarray:
         return self.encode_begin(data)()
 
     def encode_begin(self, data: np.ndarray, *, volumes: int = 1):
-        t0 = time.perf_counter()
         data = np.asarray(data, dtype=np.uint8)
         assert data.shape[0] == self.k
-        G = lrc.generator_matrix(self.lgeo)
-        parity_rows = np.ascontiguousarray(G[self.k:])
-        if _multi_device():
-            from ...parallel.mesh_codec import gf_mesh_encode_begin
-            fetch = gf_mesh_encode_begin(parity_rows, data)
-        else:
-            parity = gf_apply(parity_rows, data)
-            fetch = lambda: parity  # noqa: E731
+        if self.executor is not None:
+            return self.executor.apply_begin(self.parity_rows, data,
+                                             "encode", label="lrc",
+                                             volumes=volumes)
+        t0 = time.perf_counter()
+        from ...parallel.mesh_codec import gf_mesh_encode_begin
+        fetch = gf_mesh_encode_begin(self.parity_rows, data)
         return metered_fetch(fetch, "lrc", "encode", data.nbytes, t0,
                              volumes=volumes)
 
@@ -224,60 +234,22 @@ def _clay_repair_fn_fused(k: int, m: int, lost: int, mode: str):
 
 # -- rebuild ---------------------------------------------------------------
 
-def rebuild_lrc(base_path: str, geo: EcGeometry, missing: list[int],
-                batch_bytes: int, stats: "dict | None" = None
-                ) -> list[int]:
-    """LRC rebuild: the planner picks the cheapest read set — one local
-    group for a single loss (k/l reads instead of k), globals otherwise
-    (ops/lrc.py plan_repair; Huang et al.'s LRC pyramid argument)."""
-    t0 = time.perf_counter()
-    lgeo = lrc_geometry(geo)
-    n = geo.total_shards
-    have = [os.path.exists(base_path + to_ext(i)) for i in range(n)]
-    plan = lrc.plan_repair(lgeo, missing,
-                           available=[i for i in range(n) if have[i]])
-    inputs = {i: np.memmap(base_path + to_ext(i), dtype=np.uint8,
-                           mode="r") for i in plan.read_shards}
-    shard_size = len(next(iter(inputs.values())))
-    outputs = {i: open(base_path + to_ext(i), "wb") for i in missing}
-    bytes_read = 0
-    try:
-        for off in range(0, shard_size, batch_bytes):
-            width = min(batch_bytes, shard_size - off)
-            x = np.stack([np.asarray(inputs[i][off:off + width])
-                          for i in plan.read_shards])
-            bytes_read += x.size
-            rec = gf_apply(np.ascontiguousarray(plan.matrix), x)
-            for row, t in enumerate(plan.missing):
-                outputs[t].write(rec[row].tobytes())
-    finally:
-        for f in outputs.values():
-            f.close()
-    codec_metrics().observe("lrc", "reconstruct", bytes_read,
-                            time.perf_counter() - t0)
-    if stats is not None:
-        stats["bytes_read"] = bytes_read
-        stats["read_shards"] = list(plan.read_shards)
-        stats["plan_kind"] = plan.kind
-    return missing
-
-
-def rebuild_clay(base_path: str, geo: EcGeometry, missing: list[int],
+def rebuild_clay(base_path: str, geo: EcGeometry, plan: RepairPlan,
                  batch_bytes: int, stats: "dict | None" = None
                  ) -> list[int]:
-    """Clay rebuild.  One loss: bandwidth-optimal repair reading ONLY
-    the beta plane layers of every helper window (partial-range reads —
-    beta/alpha = 1/q of each helper's bytes).  Multi-loss: flat decode
-    from k full survivors, same engine."""
+    """Clay rebuild of plan.missing.  One loss ("clay-plane"):
+    bandwidth-optimal repair reading ONLY the beta plane layers of every
+    helper window (partial-range reads — beta/alpha = 1/q of each
+    helper's bytes).  Otherwise ("clay-decode"): flat decode from the
+    plan's k full survivors, same engine."""
     t0 = time.perf_counter()
     code = clay_matrix.code(geo.data_shards, geo.parity_shards)
-    n = geo.total_shards
     small = geo.small_block_size
     alpha, win_a = code.alpha, small // code.alpha
-    have = [os.path.exists(base_path + to_ext(i)) for i in range(n)]
+    missing = list(plan.missing)
     bytes_read = 0
 
-    if len(missing) == 1:
+    if plan.kind == "clay-plane":
         lost = missing[0]
         from ...ops import clay_structured
         from ...ops.codec import device_compute_ok
@@ -342,14 +314,16 @@ def rebuild_clay(base_path: str, geo: EcGeometry, missing: list[int],
             stats["plan_kind"] = "clay-plane-fused" if use_fused \
                 else "clay-plane"
             stats["helpers"] = list(helpers)
+            stats["read_shards"] = list(helpers)
             stats["layers_per_helper"] = len(plane)
+            stats["executor"] = "pallas" if use_fused \
+                else gf_apply_backend()
         return missing
 
-    # multi-loss: flat decode over k full survivors
-    present = tuple(i for i in range(n) if have[i])
+    # multi-loss: flat decode over the plan's k full survivors
+    chosen = plan.read_shards
     D = clay_matrix.decode_flat(geo.data_shards, geo.parity_shards,
-                                present, tuple(missing))
-    chosen = present[:geo.data_shards]
+                                chosen, tuple(missing))
     inputs = {i: np.memmap(base_path + to_ext(i), dtype=np.uint8,
                            mode="r") for i in chosen}
     shard_size = len(next(iter(inputs.values())))
@@ -380,4 +354,6 @@ def rebuild_clay(base_path: str, geo: EcGeometry, missing: list[int],
     if stats is not None:
         stats["bytes_read"] = bytes_read
         stats["plan_kind"] = "clay-decode"
+        stats["read_shards"] = list(chosen)
+        stats["executor"] = gf_apply_backend()
     return missing

@@ -125,8 +125,9 @@ class EcVolume:
         # true original-volume size from the .vif sidecar; k*shard_size is
         # ambiguous at large-row boundaries (see layout.n_large_block_rows)
         from . import load_volume_info
-        self._vif_dat_size: "int | None" = \
-            load_volume_info(base).get("dat_size")
+        info = load_volume_info(base)
+        self._vif_dat_size: "int | None" = info.get("dat_size")
+        self._lrc_construction = info.get("lrc_construction")
         # replay any existing journal so restarts see prior deletes
         for key in iterate_ecj_keys(base):
             self._tombstone_in_memory(key)
@@ -286,19 +287,26 @@ class EcVolume:
         shard set — one local group for a single loss.  If any group
         member is ALSO unreachable, fall back to probing every shard and
         re-planning globally over the set that actually answered (the
-        code tolerates any pattern the generator's rank allows)."""
+        code tolerates any pattern the generator's rank allows).  The
+        decode runs on the volume's RS executor (apply_begin), metered
+        under "lrc"."""
         from ...ops import lrc
-        from ...ops.codec import gf_apply
-        from .codes import lrc_geometry
+        from .codes import lrc_geometry, require_construction
+        try:
+            require_construction(f"{self.volume_id}",
+                                 self._lrc_construction)
+        except ValueError as e:
+            raise EcShardUnavailableError(str(e)) from None
         lgeo = lrc_geometry(self.geo)
         plan = lrc.plan_repair(lgeo, [missing_shard])
         rows = []
-        for sid in plan.read_shards:
-            raw = self._read_local_or_remote(sid, offset, size)
-            if raw is None or len(raw) != size:
-                rows = None
-                break
-            rows.append(np.frombuffer(raw, dtype=np.uint8))
+        with codec_stage("gather", "lrc", "reconstruct"):
+            for sid in plan.read_shards:
+                raw = self._read_local_or_remote(sid, offset, size)
+                if raw is None or len(raw) != size:
+                    rows = None
+                    break
+                rows.append(np.frombuffer(raw, dtype=np.uint8))
         if rows is None:
             # probe all shards; plan only over responders
             got: dict[int, np.ndarray] = {}
@@ -316,7 +324,9 @@ class EcVolume:
                     f"vol {self.volume_id} shard {missing_shard}: "
                     f"{e}") from None
             rows = [got[sid] for sid in plan.read_shards]
-        out = gf_apply(np.ascontiguousarray(plan.matrix), np.stack(rows))
+        with tracing.stage("ec.reconstruct"):
+            out = self.codec.apply_begin(plan.matrix, np.stack(rows),
+                                         "reconstruct", label="lrc")()
         return out[0].tobytes()
 
     def _reconstruct_interval_clay(self, missing_shard: int, offset: int,

@@ -28,6 +28,7 @@ import threading
 
 import numpy as np
 
+from ...ops import rs_matrix
 from ...ops.codec import RSCodec, codec_stage, metrics_backend
 from ...util import tracing
 from ..idx import index_array_to_bytes, parse_index_bytes
@@ -388,57 +389,70 @@ def _encode_group(bases: list[str], geo: EcGeometry,
 def rebuild_ec_files(base_path: str, geo: "EcGeometry | None" = None,
                      codec: RSCodec | None = None,
                      batch_bytes: int = DEFAULT_BATCH_BYTES,
-                     stats: "dict | None" = None) -> list[int]:
-    """Regenerate every missing .ecNN from the surviving ones
-    (RebuildEcFiles ec_encoder.go:61/233).  Returns rebuilt shard ids.
+                     stats: "dict | None" = None,
+                     shard_ids: "list[int] | None" = None) -> list[int]:
+    """Regenerate shards from the surviving local ones (RebuildEcFiles
+    ec_encoder.go:61/233): `shard_ids`, or every missing .ecNN when not
+    given.  Returns the rebuilt shard ids.
+
+    The read set comes from the planner the shell's ec.rebuild copies by
+    (plan.repair_plan).  RS and LRC share one pipelined loop: each batch
+    of the read shards is gathered into a buffer, the plan's matrix is
+    issued on the executor (RSCodec.apply_begin: the shard-major Pallas
+    kernel on TPU, the native codec on CPU) and the writer thread appends
+    the regenerated rows, with the gather, pack, wait, unpack and write
+    stages timed under the code's backend label and op "reconstruct".
 
     `stats`, when given, is filled with the rebuild's read accounting
-    ({"bytes_read", "plan_kind", ...}) — how the clay/LRC repair-IO
-    advantage is measured."""
+    ({"bytes_read", "plan_kind", "read_shards", "executor"}) — how the
+    clay/LRC repair-IO advantage is measured."""
+    from . import geometry_from_vif, load_volume_info
+    from .plan import repair_plan
     if geo is None:
-        from . import geometry_from_vif
         geo = geometry_from_vif(base_path)
+    if geo.code_kind == "lrc":
+        from .codes import require_construction
+        require_construction(
+            os.path.basename(base_path),
+            load_volume_info(base_path).get("lrc_construction"))
     n = geo.total_shards
     have = [os.path.exists(base_path + to_ext(i)) for i in range(n)]
-    missing = [i for i in range(n) if not have[i]]
+    if shard_ids is None:
+        missing = [i for i in range(n) if not have[i]]
+    else:
+        missing = sorted({int(s) for s in shard_ids})
     if not missing:
         return []
-    if sum(have) < geo.data_shards:
-        raise ValueError(
-            f"need >= {geo.data_shards} shards to rebuild, have {sum(have)}")
+    plan = repair_plan(geo, missing, [i for i in range(n) if have[i]])
     if geo.code_kind == "clay" and codec is None:
         from .codes import rebuild_clay
-        return rebuild_clay(base_path, geo, missing, batch_bytes,
-                            stats=stats)
-    if geo.code_kind == "lrc" and codec is None:
-        from .codes import rebuild_lrc
-        return rebuild_lrc(base_path, geo, missing, batch_bytes,
-                           stats=stats)
-    codec = _codec_for(geo, codec)
+        return rebuild_clay(base_path, geo, plan, batch_bytes, stats=stats)
+    codec, backend, begin = _rebuild_executor(geo, codec, plan)
+    read = plan.read_shards
     inputs = {i: np.memmap(base_path + to_ext(i), dtype=np.uint8, mode="r")
-              for i in range(n) if have[i]}
-    shard_size = len(next(iter(inputs.values())))
+              for i in read}
+    shard_size = len(inputs[read[0]])
     for i, arr in inputs.items():
         if len(arr) != shard_size:
             raise ValueError(f"shard {i} size {len(arr)} != {shard_size}")
     outputs = {i: open(base_path + to_ext(i), "wb") for i in missing}
-    used = [i for i in range(n) if have[i]][:geo.data_shards]
-    bytes_read = len(used) * shard_size
+    pool = _BufferPool(PIPELINE_DEPTH + 2,
+                       (len(read), min(batch_bytes, shard_size)))
 
     def produce():
         for off in range(0, shard_size, batch_bytes):
             width = min(batch_bytes, shard_size - off)
-            # memmap slices stay lazy; reconstruct materializes only the
-            # first k present shards it actually decodes from
-            shards: list[np.ndarray | None] = [
-                inputs[i][off:off + width] if have[i] else None
-                for i in range(n)]
-            yield _begin_reconstruct(codec, shards)
+            with codec_stage("gather", backend, "reconstruct"):
+                x = pool.next()[:, :width]
+                for row, i in enumerate(read):
+                    x[row] = inputs[i][off:off + width]
+            yield begin(x)
 
     def consume(fetch):
-        rebuilt = fetch()
-        for i in missing:
-            outputs[i].write(rebuilt[i])
+        rows = fetch()
+        with codec_stage("write", backend, "reconstruct"):
+            for row, i in enumerate(missing):
+                outputs[i].write(rows[row])
 
     try:
         _pipelined(produce(), consume, _pipeline_depth(codec))
@@ -446,10 +460,44 @@ def rebuild_ec_files(base_path: str, geo: "EcGeometry | None" = None,
         for f in outputs.values():
             f.close()
     if stats is not None:
-        stats["bytes_read"] = bytes_read
-        stats["plan_kind"] = "rs-full"
-        stats["read_shards"] = used
+        stats["bytes_read"] = len(read) * shard_size
+        stats["plan_kind"] = plan.kind
+        stats["read_shards"] = list(read)
+        stats["executor"] = getattr(codec, "backend", "")
     return missing
+
+
+def _rebuild_executor(geo: EcGeometry, codec, plan):
+    """(codec, backend label, begin(x) -> fetch() -> the rows of
+    plan.missing) for the rebuild loop, x the plan's read shards.  LRC
+    applies the plan's matrix on its RSCodec executor; RS decodes with
+    the production codec's own generator.  A codec without apply_begin
+    (MeshCodec, or one a caller passes) reconstructs from the plan's
+    shards in their slots."""
+    read, missing = list(plan.read_shards), list(plan.missing)
+    if geo.code_kind == "lrc" and codec is None:
+        codec = RSCodec(geo.data_shards, geo.parity_shards)
+        label = "lrc"
+    else:
+        codec = _codec_for(geo, codec)
+        label = metrics_backend(codec)
+    if hasattr(codec, "apply_begin"):
+        M = plan.matrix if plan.matrix is not None \
+            else rs_matrix.decode_matrix(codec.gen, read, missing)
+        return codec, label, lambda x: codec.apply_begin(
+            M, x, "reconstruct", label=label)
+
+    def begin(x):
+        shards: list[np.ndarray | None] = [None] * geo.total_shards
+        for row, i in enumerate(read):
+            shards[i] = x[row]
+        fetch = _begin_reconstruct(codec, shards)
+
+        def rows():
+            out = fetch()
+            return [out[i] for i in missing]
+        return rows
+    return codec, label, begin
 
 
 def rebuild_ec_files_batch(base_paths: list[str],

@@ -793,8 +793,15 @@ class Volume:
             self.data_backend.close()
 
     def destroy(self) -> None:
+        """Remove the volume's files.  The .vif stays where EC shards were
+        sealed from this volume (an .ecx beside it): it is then their
+        geometry record, which ec.encode's VolumeDelete must not take
+        from the shards it leaves on this server."""
         self.close()
+        sealed = os.path.exists(self.base_path + ".ecx")
         for ext in (".dat", ".idx", ".ldb", ".cpd", ".cpx", ".vif", ".note"):
+            if ext == ".vif" and sealed:
+                continue
             p = self.base_path + ext
             if os.path.exists(p):
                 os.remove(p)

@@ -173,6 +173,17 @@ def add(key: str, value: float) -> None:
             tags[key] = tags.get(key, 0) + value
 
 
+def tag(key: str, value) -> None:
+    """Set tag `key` of the thread's open span to `value` (no-op outside
+    a span, or with tracing off)."""
+    if not _ENABLED:
+        return
+    tags = getattr(_ctx, "tags", None)
+    if tags is not None:
+        with _TAGS_LOCK:
+            tags[key] = value
+
+
 def copy_tags(tags: "dict | None") -> dict:
     """A closing span's tags, copied under the lock stage()/add() sum
     under (a propagated task may still be adding)."""

@@ -1683,10 +1683,22 @@ class VolumeServer:
         return {}
 
     def _rpc_ec_rebuild(self, req: dict) -> dict:
+        """VolumeEcShardsRebuild: regenerate `shard_ids` from the local
+        shards (every absent shard when not given), reading the set the
+        shared planner names (storage/ec/plan.py).  The span carries
+        `plan_kind`, `read_shards` (how many) and `bytes_read`."""
         base = self._base_path(int(req["volume_id"]),
                                req.get("collection", ""))
         stats: dict = {}
-        rebuilt = ec_pkg.rebuild_ec_files(base, stats=stats)
+        shard_ids = req.get("shard_ids")
+        rebuilt = ec_pkg.rebuild_ec_files(
+            base, stats=stats,
+            shard_ids=None if shard_ids is None
+            else [int(s) for s in shard_ids])
+        if stats:
+            tracing.tag("plan_kind", stats["plan_kind"])
+            tracing.add("read_shards", len(stats["read_shards"]))
+            tracing.add("bytes_read", stats["bytes_read"])
         # stats surface the clay/LRC repair-IO advantage to operators
         # (bytes_read, plan_kind) — see storage/ec/codes.py — both in the
         # RPC reply (shell ec.rebuild prints it) and /metrics counters
@@ -1804,7 +1816,9 @@ class VolumeServer:
         return {"data_shards": info["data_shards"],
                 "parity_shards": info["parity_shards"],
                 "total_shards": info["data_shards"]
-                + info["parity_shards"]}
+                + info["parity_shards"],
+                "code_kind": info.get("code_kind", "rs"),
+                "lrc_locals": info.get("lrc_locals", 0)}
 
     def _rpc_ec_shard_read(self, requests):
         """Stream shard bytes (VolumeEcShardRead volume_server.proto:82)."""

@@ -84,6 +84,36 @@ def test_pallas_interpret_reconstruct():
         assert np.array_equal(got[i], full[i]), f"shard {i}"
 
 
+@pytest.mark.parametrize("backend", ["numpy", "native", "jax", "pallas"])
+@pytest.mark.parametrize("mo,ki", [(1, 6), (4, 12), (2, 12)],
+                         ids=["lrc-local", "lrc-encode", "lrc-2loss"])
+def test_apply_begin_any_small_matrix(backend, mo, ki):
+    """apply_begin runs an arbitrary GF(2^8) matrix up to the codec's
+    own [m, k] (LRC's group XOR, its parity rows, a global decode) on
+    every backend, the shard-major Pallas kernel included, metered under
+    the label it is given."""
+    from seaweedfs_tpu.ops.codec import codec_metrics
+    codec = RSCodec(12, 4, backend=backend, block_b=256,
+                    interpret=backend == "pallas")
+    M = rng.integers(0, 256, (mo, ki), dtype=np.uint8)
+    x = rng.integers(0, 256, (ki, 777), dtype=np.uint8)
+    before = codec_metrics().bytes.value("lrc", "reconstruct")
+    got = codec.apply_begin(M, x, "reconstruct", label="lrc")()
+    assert np.array_equal(got, gf256.matmul(M, x))
+    assert codec_metrics().bytes.value("lrc", "reconstruct") \
+        == before + x.nbytes
+
+
+def test_apply_begin_refuses_a_matrix_beyond_the_codec():
+    codec = RSCodec(10, 4, backend="numpy")
+    with pytest.raises(ValueError):
+        codec.apply_begin(np.ones((5, 10), np.uint8),
+                          np.zeros((10, 8), np.uint8), "encode")
+    with pytest.raises(ValueError):
+        codec.apply_begin(np.ones((1, 12), np.uint8),
+                          np.zeros((12, 8), np.uint8), "encode")
+
+
 def test_plane_major_permutation_roundtrip():
     from seaweedfs_tpu.ops.rs_pallas import to_plane_major
     k, m = 10, 4

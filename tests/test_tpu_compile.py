@@ -69,6 +69,8 @@ def _compile(fn, *args):
     (10, 4, 64),    # the smoke's direct call: [10, 64, 8 MiB], 5.4 GB
     (16, 8, 40),    # wide stripes at a comparable byte volume
     (28, 4, 16),
+    (6, 1, 8),      # LRC(12,2,2) local repair: 6 rows in, 1 out
+    (12, 4, 8),     # LRC(12,2,2) encode on RS's executor
 ])
 def test_sm_kernel_compiles(one_chip, k, m, v):
     block_b = rs_pallas.sm_block_b_for(k, m)

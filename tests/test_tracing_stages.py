@@ -348,3 +348,28 @@ def test_stage_series_in_the_exposition():
     text = codec_metrics().registry.render()
     for s in STAGES:
         assert f"# TYPE seaweedfs_codec_{s}_seconds_total counter" in text
+
+
+def test_lrc_encode_and_rebuild_raise_every_stage(tmp_path, monkeypatch):
+    """LRC's encode and its rebuild, on RS's executor, record the
+    gather, pack, wait, unpack and write stages under backend "lrc", as
+    the RS encoder does under its own label."""
+    from seaweedfs_tpu.storage.ec import codes
+    monkeypatch.setenv("WEED_EC_BACKEND", "jax")
+    monkeypatch.setattr(codes, "_multi_device", lambda: False)  # one chip
+    geo = EcGeometry(data_shards=12, parity_shards=4, code_kind="lrc",
+                     lrc_locals=2, large_block_size=16 * 1024,
+                     small_block_size=1024)
+    _make_volume(str(tmp_path))
+    base = str(tmp_path / "7")
+    enc0 = _stage_values("lrc", "encode")
+    ec.encode_volume_to_ec(base, version=3, geo=geo)
+    enc1 = _stage_values("lrc", "encode")
+    assert all(enc1[s] > enc0[s] for s in STAGES if s != "cpu"), (enc0, enc1)
+    os.remove(base + ec.to_ext(0))
+    rec0 = _stage_values("lrc", "reconstruct")
+    stats: dict = {}
+    assert ec.rebuild_ec_files(base, stats=stats) == [0]
+    rec1 = _stage_values("lrc", "reconstruct")
+    assert all(rec1[s] > rec0[s] for s in STAGES if s != "cpu"), (rec0, rec1)
+    assert stats["executor"] == "jax" and stats["plan_kind"] == "local"
