@@ -22,7 +22,10 @@ Standard raft (Ongaro & Ousterhout) with the safety-relevant details:
   dict once the log exceeds max_log_entries; lagging followers catch up
   via InstallSnapshot;
 - optional state_dir persists term/vote/log/snapshot (JSON files) so a
-  restarted master rejoins with vote and log intact.
+  restarted master rejoins with vote and log intact.  Term/vote and log
+  writes are appends: replacing a file over an existing one makes ext4
+  flush it (auto_da_alloc), tens of ms under the node lock on every
+  vote, which outlasts an election timeout and livelocks elections.
 
 Transport is the repo's JSON-over-gRPC mesh (pb/rpc.py): the three RPCs
 are unary methods on the "Raft" service of the master's RpcServer.
@@ -47,6 +50,8 @@ from ..util.weedlog import logger
 LOG = logger(__name__)
 
 FOLLOWER, CANDIDATE, LEADER = "follower", "candidate", "leader"
+# term/vote records kept in meta.jsonl before it is folded to one line
+_META_MAX_LINES = 1024
 
 
 class NotLeaderError(RpcError):
@@ -140,6 +145,7 @@ class RaftNode:
         # thread spawning)
         self._cond = locks.Condition(name="RaftNode._cond")
         self._election_deadline = 0.0
+        self._meta_lines = 0
         if state_dir:
             os.makedirs(state_dir, exist_ok=True)
             self._load_state()
@@ -173,12 +179,25 @@ class RaftNode:
 
     # -- persistence --------------------------------------------------------
     def _persist_meta(self) -> None:
+        """Append term/vote to meta.jsonl; the last whole line wins on
+        load, so a torn append falls back to the previous record (the
+        torn vote was never answered: replies follow the write).  A rare
+        rewrite folds the file to one line at _META_MAX_LINES."""
         if not self.state_dir:
             return
-        tmp = os.path.join(self.state_dir, ".meta.tmp")
-        with open(tmp, "w") as f:
-            json.dump({"term": self.term, "voted_for": self.voted_for}, f)
-        os.replace(tmp, os.path.join(self.state_dir, "meta.json"))
+        line = json.dumps({"term": self.term, "voted_for": self.voted_for},
+                          separators=(",", ":")) + "\n"
+        path = os.path.join(self.state_dir, "meta.jsonl")
+        if self._meta_lines >= _META_MAX_LINES:
+            tmp = os.path.join(self.state_dir, ".meta.tmp")
+            with open(tmp, "w") as f:
+                f.write(line)
+            os.replace(tmp, path)
+            self._meta_lines = 1
+            return
+        with open(path, "a") as f:
+            f.write(line)
+        self._meta_lines += 1
 
     def _persist_log(self) -> None:
         """Full rewrite — only for truncation/compaction; plain appends go
@@ -207,10 +226,24 @@ class RaftNode:
         os.replace(tmp, os.path.join(self.state_dir, "snap.json"))
 
     def _load_state(self) -> None:
-        meta_p = os.path.join(self.state_dir, "meta.json")
+        meta = None
+        meta_p = os.path.join(self.state_dir, "meta.jsonl")
+        legacy_p = os.path.join(self.state_dir, "meta.json")
         if os.path.exists(meta_p):
             with open(meta_p) as f:
+                for line in f:
+                    self._meta_lines += 1
+                    try:
+                        meta = json.loads(line)
+                    except ValueError:
+                        # torn tail: the previous record holds, and the
+                        # next write rewrites the file so no append
+                        # lands on the torn line
+                        self._meta_lines = _META_MAX_LINES
+        elif os.path.exists(legacy_p):     # single-record older layout
+            with open(legacy_p) as f:
                 meta = json.load(f)
+        if meta is not None:
             self.term = meta.get("term", 0)
             self.voted_for = meta.get("voted_for")
         snap_p = os.path.join(self.state_dir, "snap.json")

@@ -267,7 +267,13 @@ def do_ec_rebuild(env: CommandEnv, vid: int, collection: str = "") -> dict:
     single LRC loss.  The rebuilder is the server holding the most of
     that set (ties: the most shards of the volume, then the lowest id),
     so the copy is as small as the placement allows; it regenerates only
-    the missing shards and then deletes its temporary copies."""
+    the missing shards and then deletes its temporary copies.
+
+    A single clay loss (plan "clay-plane") copies of each remote helper
+    only the beta repair planes its repair reads, a quarter of the shard
+    for clay(10,4) (`repair_planes_of` on VolumeEcShardsCopy); the
+    rebuild reads and removes those plane files, and a failed copy or
+    rebuild has them removed.  Every other plan copies whole shards."""
     topo = env.topology()
     shard_map = collect_ec_shard_map(topo).get(vid, {})
     present = sorted({s for ids in shard_map.values() for s in ids})
@@ -278,27 +284,45 @@ def do_ec_rebuild(env: CommandEnv, vid: int, collection: str = "") -> dict:
     if not missing:
         return {"volume_id": vid, "rebuilt": [], "copied": []}
     try:
-        rebuilder_id, _, copies = plan_rebuild(geo, missing, shard_map)
+        rebuilder_id, plan, copies = plan_rebuild(geo, missing, shard_map)
     except ValueError as e:
         raise ShellError(f"ec volume {vid}: {e}") from None
     rebuilder = env.volume_server(grpc_by_id[rebuilder_id])
+    # a single clay loss copies only the helpers' repair planes
+    planes = {"repair_planes_of": missing[0]} \
+        if plan.kind == "clay-plane" else {}
     copied: list[int] = []
-    for node_id, take in copies.items():
-        rebuilder.call("VolumeEcShardsCopy", {
-            "volume_id": vid, "collection": collection,
-            "shard_ids": take, "copy_ecx_files": False,
-            "source_data_node": grpc_by_id[node_id]}, timeout=3600)
-        copied += take
-    out = rebuilder.call("VolumeEcShardsRebuild",
-                         {"volume_id": vid, "collection": collection,
-                          "shard_ids": missing}, timeout=3600)
+    try:
+        for node_id, take in copies.items():
+            rebuilder.call("VolumeEcShardsCopy", {
+                "volume_id": vid, "collection": collection,
+                "shard_ids": take, "copy_ecx_files": False,
+                "source_data_node": grpc_by_id[node_id], **planes},
+                timeout=3600)
+            copied += take
+        out = rebuilder.call("VolumeEcShardsRebuild",
+                             {"volume_id": vid, "collection": collection,
+                              "shard_ids": missing}, timeout=3600)
+    except RpcError:
+        if planes:
+            # the rebuild removes the plane files it read; after a
+            # failure they would lie there unused
+            try:
+                rebuilder.call("VolumeEcShardsDelete", {
+                    "volume_id": vid, "collection": collection,
+                    "shard_ids": sorted(s for t in copies.values()
+                                        for s in t), **planes})
+            except RpcError as e:
+                LOG.warning("ec.rebuild volume %d: plane files left on "
+                            "%s: %s", vid, rebuilder_id, e)
+        raise
     rebuilt = out.get("rebuilt_shard_ids", [])
     rebuilder.call("VolumeEcShardsMount",
                    {"volume_id": vid, "collection": collection,
                     "shard_ids": rebuilt})
     # drop the temp copies that still live elsewhere
     stale = [s for s in copied if s not in rebuilt]
-    if stale:
+    if stale and not planes:
         rebuilder.call("VolumeEcShardsDelete",
                        {"volume_id": vid, "collection": collection,
                         "shard_ids": stale})

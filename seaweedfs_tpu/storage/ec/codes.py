@@ -25,6 +25,7 @@ that kernel and go through ops.codec.gf_apply, or its fused kernels.
 from __future__ import annotations
 
 import functools
+import os
 import time
 
 import numpy as np
@@ -234,19 +235,56 @@ def _clay_repair_fn_fused(k: int, m: int, lost: int, mode: str):
 
 # -- rebuild ---------------------------------------------------------------
 
+PLANE_MESSAGE_BYTES = 1 << 20
+
+
+def plane_file(base_path: str, helper: int, lost: int) -> str:
+    """Where a rebuilder keeps the repair planes of `helper` copied for
+    the loss of `lost` (`<base>.ec03.planes07`): a name no shard scan
+    (`.ecNN`) matches, so it is never mounted or counted as a shard."""
+    return f"{base_path}{to_ext(helper)}.planes{lost:02d}"
+
+
+def iter_repair_planes(path: str, geo: EcGeometry, lost: int):
+    """The repair planes of one helper's shard file for the loss of
+    `lost`: of every small-block window the beta layers repair_flat
+    names, in its order, read as rebuild_clay reads a local helper (a
+    memmap and a take, so only those pages are read).  Yields contiguous
+    [wn, beta, win_a] uint8 arrays of whole windows, at most
+    PLANE_MESSAGE_BYTES each but at least one window; together they are
+    the shard size / q."""
+    k, m = geo.data_shards, geo.parity_shards
+    _, plane, _ = clay_matrix.repair_flat(k, m, lost)
+    alpha = clay_matrix.code(k, m).alpha
+    small = geo.small_block_size
+    shard = np.memmap(path, dtype=np.uint8, mode="r")
+    if len(shard) % small:
+        raise ValueError(f"{path}: {len(shard)} B is not whole windows "
+                         f"of {small} B")
+    windows = shard.reshape(-1, alpha, small // alpha)
+    plane_idx = np.asarray(plane)
+    step = max(1, PLANE_MESSAGE_BYTES // (len(plane) * (small // alpha)))
+    for w0 in range(0, len(windows), step):
+        yield windows[w0:w0 + step][:, plane_idx]
+
+
 def rebuild_clay(base_path: str, geo: EcGeometry, plan: RepairPlan,
-                 batch_bytes: int, stats: "dict | None" = None
-                 ) -> list[int]:
+                 batch_bytes: int, stats: "dict | None" = None,
+                 planes: "dict[int, str] | None" = None) -> list[int]:
     """Clay rebuild of plan.missing.  One loss ("clay-plane"):
     bandwidth-optimal repair reading ONLY the beta plane layers of every
-    helper window (partial-range reads — beta/alpha = 1/q of each
-    helper's bytes).  Otherwise ("clay-decode"): flat decode from the
-    plan's k full survivors, same engine."""
+    helper window, beta/alpha = 1/q of each helper's bytes: a local
+    helper's from its shard file, a remote one's from the plane file its
+    copy left (`planes`, {helper: plane_file path}, holding those layers
+    alone, [windows, beta, win_a]), which is removed once the lost shard
+    is written.  Otherwise ("clay-decode"): flat decode from the plan's
+    k full survivors, same engine."""
     t0 = time.perf_counter()
     code = clay_matrix.code(geo.data_shards, geo.parity_shards)
     small = geo.small_block_size
     alpha, win_a = code.alpha, small // code.alpha
     missing = list(plan.missing)
+    planes = planes or {}
     bytes_read = 0
 
     if plan.kind == "clay-plane":
@@ -261,15 +299,29 @@ def rebuild_clay(base_path: str, geo: EcGeometry, plan: RepairPlan,
         # the [alpha, (n-1)*beta] flat matmul + host transposes
         use_fused = (clay_structured.use_fused_engine()
                      and device_compute_ok() and win_a % 128 == 0)
-        inputs = {h: np.memmap(base_path + to_ext(h), dtype=np.uint8,
-                               mode="r") for h in helpers}
-        shard_size = len(next(iter(inputs.values())))
-        assert shard_size % small == 0, (shard_size, small)
+        # every helper as [windows, layers, win_a]: alpha layers from a
+        # shard file, the beta plane layers alone from a plane file
+        inputs = {h: np.memmap(planes.get(h, base_path + to_ext(h)),
+                               dtype=np.uint8, mode="r").reshape(
+                                   -1, len(plane) if h in planes else alpha,
+                                   win_a) for h in helpers}
+        n_win = len(inputs[helpers[0]])
+        if any(len(v) != n_win for v in inputs.values()):
+            counts = sorted((h, len(v)) for h, v in inputs.items())
+            raise ValueError(f"clay repair of shard {lost}: the helpers' "
+                             f"window counts differ: {counts}")
         wins_per_batch = max(1, batch_bytes // small)
         plane_idx = np.asarray(plane)
+
+        def layers(h: int, w0: int, wn: int) -> np.ndarray:
+            """Helper h's plane layers of windows [w0, w0+wn):
+            [wn, beta, win_a], the partial-range read of a shard file."""
+            span = inputs[h][w0:w0 + wn]
+            return span if h in planes else span[:, plane_idx]
+
         with open(base_path + to_ext(lost), "wb") as out:
-            for w0 in range(0, shard_size // small, wins_per_batch):
-                wn = min(wins_per_batch, shard_size // small - w0)
+            for w0 in range(0, n_win, wins_per_batch):
+                wn = min(wins_per_batch, n_win - w0)
                 if use_fused:
                     # helper-major [H, wn, beta, win_a] — the gather is
                     # the partial-range plane read, no transposes; the
@@ -278,9 +330,7 @@ def rebuild_clay(base_path: str, geo: EcGeometry, plan: RepairPlan,
                     x4 = np.empty((len(helpers), wn, len(plane), win_a),
                                   dtype=np.uint8)
                     for hi, h in enumerate(helpers):
-                        span = inputs[h][w0 * small:(w0 + wn) * small]
-                        x4[hi] = span.reshape(wn, alpha, win_a)[:,
-                                                                plane_idx]
+                        x4[hi] = layers(h, w0, wn)
                     bytes_read += x4.size
                     import jax
                     import jax.numpy as jnp
@@ -295,18 +345,19 @@ def rebuild_clay(base_path: str, geo: EcGeometry, plan: RepairPlan,
                 x = np.empty((len(helpers) * len(plane), wn * win_a),
                              dtype=np.uint8)
                 for hi, h in enumerate(helpers):
-                    span = inputs[h][w0 * small:(w0 + wn) * small]
-                    layers = span.reshape(wn, alpha, win_a)[:, plane_idx]
+                    part = layers(h, w0, wn)
                     # [wn, beta, win_a] -> [beta, wn*win_a]
                     x[hi * len(plane):(hi + 1) * len(plane)] = \
                         np.ascontiguousarray(
-                            layers.transpose(1, 0, 2)).reshape(
+                            part.transpose(1, 0, 2)).reshape(
                                 len(plane), -1)
-                    bytes_read += layers.size
+                    bytes_read += part.size
                 rec = gf_apply(R, x)  # [alpha, wn*win_a]
                 rec = np.ascontiguousarray(
                     rec.reshape(alpha, wn, win_a).transpose(1, 0, 2))
                 out.write(rec.tobytes())
+        for path in planes.values():
+            os.remove(path)
         codec_metrics().observe("clay", "reconstruct", bytes_read,
                                 time.perf_counter() - t0)
         if stats is not None:
@@ -316,6 +367,8 @@ def rebuild_clay(base_path: str, geo: EcGeometry, plan: RepairPlan,
             stats["helpers"] = list(helpers)
             stats["read_shards"] = list(helpers)
             stats["layers_per_helper"] = len(plane)
+            stats["copy"] = "planes" if planes else "whole"
+            stats["helpers_from_planes"] = len(planes)
             stats["executor"] = "pallas" if use_fused \
                 else gf_apply_backend()
         return missing
@@ -355,5 +408,7 @@ def rebuild_clay(base_path: str, geo: EcGeometry, plan: RepairPlan,
         stats["bytes_read"] = bytes_read
         stats["plan_kind"] = "clay-decode"
         stats["read_shards"] = list(chosen)
+        stats["copy"] = "whole"
+        stats["helpers_from_planes"] = 0
         stats["executor"] = gf_apply_backend()
     return missing

@@ -254,8 +254,10 @@ class EcVolume:
         Kind dispatch: LRC repairs a single loss from its LOCAL GROUP
         only (k/l interval reads instead of k); clay decodes from k
         survivors over whole alpha-layer windows (the beta-plane partial
-        read path is reserved for rebuild, where helpers are local files
-        and scattered range reads are cheap — see codes.rebuild_clay)."""
+        read path is reserved for rebuild, which reads the planes of
+        local helpers from their shard files and, for a single loss, is
+        copied only the planes of remote ones — see codes.rebuild_clay
+        and the shell's ec.rebuild)."""
         if self.geo.code_kind == "lrc":
             return self._reconstruct_interval_lrc(missing_shard, offset,
                                                   size)

@@ -403,8 +403,14 @@ def rebuild_ec_files(base_path: str, geo: "EcGeometry | None" = None,
     the regenerated rows, with the gather, pack, wait, unpack and write
     stages timed under the code's backend label and op "reconstruct".
 
+    A single clay loss also counts as present a helper of which only the
+    repair planes for that loss were copied here (codes.plane_file, the
+    shell's ec.rebuild copies remote helpers so); rebuild_clay reads and
+    then removes those files.
+
     `stats`, when given, is filled with the rebuild's read accounting
-    ({"bytes_read", "plan_kind", "read_shards", "executor"}) — how the
+    ({"bytes_read", "plan_kind", "read_shards", "executor"}; clay adds
+    "copy", "planes" or "whole", and "helpers_from_planes") — how the
     clay/LRC repair-IO advantage is measured."""
     from . import geometry_from_vif, load_volume_info
     from .plan import repair_plan
@@ -423,10 +429,24 @@ def rebuild_ec_files(base_path: str, geo: "EcGeometry | None" = None,
         missing = sorted({int(s) for s in shard_ids})
     if not missing:
         return []
-    plan = repair_plan(geo, missing, [i for i in range(n) if have[i]])
+    available = [i for i in range(n) if have[i]]
     if geo.code_kind == "clay" and codec is None:
-        from .codes import rebuild_clay
-        return rebuild_clay(base_path, geo, plan, batch_bytes, stats=stats)
+        from .codes import plane_file, rebuild_clay
+        # a helper whose repair planes for this very loss were copied
+        # here is present for the single-loss plane repair, and only
+        # for it: a decode reads whole shards
+        planes = {}
+        if len(missing) == 1:
+            planes = {i: plane_file(base_path, i, missing[0])
+                      for i in range(n) if not have[i] and i not in missing}
+            planes = {i: p for i, p in planes.items() if os.path.exists(p)}
+        plan = repair_plan(geo, missing, sorted(set(available) | set(planes)))
+        if planes and plan.kind != "clay-plane":
+            planes = {}
+            plan = repair_plan(geo, missing, available)
+        return rebuild_clay(base_path, geo, plan, batch_bytes, stats=stats,
+                            planes=planes)
+    plan = repair_plan(geo, missing, available)
     codec, backend, begin = _rebuild_executor(geo, codec, plan)
     read = plan.read_shards
     inputs = {i: np.memmap(base_path + to_ext(i), dtype=np.uint8, mode="r")

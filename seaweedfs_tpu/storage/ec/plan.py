@@ -7,7 +7,9 @@ copies it only the rest; the rebuilder's VolumeEcShardsRebuild
 reads exactly that set.  Per code family:
 
   rs    any k survivors, those on the rebuilder (`prefer`) first
-  clay  one loss: the d = n-1 helpers (their beta planes are read);
+  clay  one loss: the d = n-1 helpers (their beta planes are read,
+        and the shell copies the rebuilder only those planes of each
+        remote helper, a quarter of the shard for clay(10,4));
         more losses: k survivors, as RS
   lrc   ops/lrc.plan_repair: one lost data or local-parity shard reads
         the other members of its local group; anything else reads k

@@ -1715,11 +1715,17 @@ class VolumeServer:
         its time: `recv_s` (waiting on the stream), `frame_s` (parsing
         each message's envelope), `write_s` (file writes and the final
         renames), `bytes` received and `raw_bytes`, the part of them that
-        came raw (all of it: the chunks are `bytes` values)."""
+        came raw (all of it: the chunks are `bytes` values).
+
+        With `repair_planes_of` (the lost shard of a single clay loss,
+        set by the shell's ec.rebuild) only each helper's repair planes
+        are copied, to a plane file (`_ec_copy_planes`)."""
         vid = int(req["volume_id"])
         collection = req.get("collection", "")
         base = self._base_path(vid, collection)
         src = POOL.client(req["source_data_node"], "VolumeServer")
+        if req.get("repair_planes_of") is not None:
+            return self._ec_copy_planes(req, base, src)
         exts = [to_ext(int(s)) for s in req.get("shard_ids", [])]
         if req.get("copy_ecx_files", True):
             exts += [".ecx", ".ecj", ".vif"]
@@ -1746,9 +1752,80 @@ class VolumeServer:
                 os.replace(tmp, base + ext)
         return {}
 
+    def _ec_copy_planes(self, req: dict, base: str, src) -> dict:
+        """The plane copy of a clay repair: of each shard in `shard_ids`
+        (a helper), the layers the repair of shard `repair_planes_of`
+        reads, streamed by the source's CopyFile into a .tmp and renamed
+        to its plane file (codes.plane_file), which no shard scan
+        matches.  A stream whose length is not the shard size / q (a
+        source that ignored the field sends the whole shard) fails the
+        RPC, naming the source, and leaves no file of this call behind.
+        The span carries `plane_layers` (beta) beside `bytes`."""
+        import glob
+
+        from ..ops import clay_matrix
+        from ..storage.ec.codes import plane_file
+        lost = int(req["repair_planes_of"])
+        geo = ec_pkg.geometry_from_vif(base)
+        if geo.code_kind != "clay":
+            raise RpcError(f"repair planes of a {geo.code_kind} volume")
+        code = clay_matrix.code(geo.data_shards, geo.parity_shards)
+        tracing.tag("plane_layers", code.beta)
+        local = sorted(glob.glob(glob.escape(base) + ".ec[0-9][0-9]"))
+        if not local:
+            raise RpcError(f"no shard of {base} here to size the repair "
+                           f"planes by")
+        want = os.path.getsize(local[0]) // code.q
+        done: list[str] = []
+        tmp = ""
+        try:
+            for s in req.get("shard_ids", []):
+                dest = plane_file(base, int(s), lost)
+                tmp = dest + ".tmp"
+                got = 0
+                with open(tmp, "wb") as f:
+                    for r in src.stream("CopyFile", iter([{
+                            "volume_id": req["volume_id"],
+                            "collection": req.get("collection", ""),
+                            "ext": to_ext(int(s)),
+                            "repair_planes_of": lost}])):
+                        data = r["file_content"]
+                        got += len(data)
+                        tracing.add("bytes", len(data))
+                        if got > want:
+                            break
+                        with tracing.stage("write"):
+                            f.write(data)
+                if got != want:
+                    raise RpcError(
+                        f"repair planes of shard {s} for shard {lost} from "
+                        f"{req['source_data_node']}: "
+                        f"{'over ' if got > want else ''}{got} bytes, "
+                        f"want {want} (shard size / q {code.q})")
+                with tracing.stage("write"):
+                    os.replace(tmp, dest)
+                done.append(dest)
+        except BaseException:
+            for p in done + [tmp]:
+                if os.path.exists(p):
+                    os.remove(p)
+            raise
+        return {}
+
     def _rpc_ec_delete(self, req: dict) -> dict:
+        """VolumeEcShardsDelete: remove shard files, and the index files
+        once no shard remains.  With `repair_planes_of` it removes only
+        the plane files of `shard_ids` copied for that loss (ec.rebuild's
+        clean-up after a failed plane repair)."""
         vid = int(req["volume_id"])
         base = self._base_path(vid, req.get("collection", ""))
+        if req.get("repair_planes_of") is not None:
+            from ..storage.ec.codes import plane_file
+            for s in req.get("shard_ids", []):
+                p = plane_file(base, int(s), int(req["repair_planes_of"]))
+                if os.path.exists(p):
+                    os.remove(p)
+            return {}
         for s in req.get("shard_ids", []):
             p = base + to_ext(int(s))
             if os.path.exists(p):
@@ -1855,13 +1932,20 @@ class VolumeServer:
         The span's tags split its time: `read_s` (disk), `frame_s`
         (building each message's envelope, serialized on this thread),
         `bytes` sent and `raw_bytes`, the part of them sent raw (all of
-        it: each chunk is yielded as a `bytes` value)."""
+        it: each chunk is yielded as a `bytes` value).
+
+        With `repair_planes_of` (a lost shard of a clay volume) it streams
+        of the shard file `ext` only the layers that shard's repair reads
+        (`_copy_planes`)."""
         for req in requests:
             base = self._base_path(int(req["volume_id"]),
                                    req.get("collection", ""))
             path = base + req["ext"]
             if not os.path.exists(path):
                 raise RpcError(f"{path} not found")
+            if req.get("repair_planes_of") is not None:
+                yield from self._copy_planes(base, path, req)
+                continue
             with open(path, "rb") as f:
                 while True:
                     with tracing.stage("read"):
@@ -1870,3 +1954,31 @@ class VolumeServer:
                         break
                     tracing.add("bytes", len(chunk))
                     yield {"file_content": chunk}
+
+    def _copy_planes(self, base: str, path: str, req: dict):
+        """CopyFile's plane branch: window by window, the beta layers of
+        the helper shard at `path` that the repair of shard
+        `repair_planes_of` reads, in rebuild_clay's order
+        (codes.iter_repair_planes: a memmap and a take), several windows
+        to a message of at most 1 MiB.  The span carries `plane_layers`
+        (beta) beside `bytes`."""
+        from ..ops import clay_matrix
+        from ..storage.ec.codes import iter_repair_planes
+        geo = ec_pkg.geometry_from_vif(base)
+        lost = int(req["repair_planes_of"])
+        ext = req["ext"]
+        if geo.code_kind != "clay":
+            raise RpcError(f"repair planes of a {geo.code_kind} volume")
+        if not (0 <= lost < geo.total_shards) or ext == to_ext(lost) \
+                or not ext.startswith(".ec") or not ext[3:].isdigit():
+            raise RpcError(f"no repair planes of {ext} for shard {lost}")
+        tracing.tag("plane_layers", clay_matrix.code(
+            geo.data_shards, geo.parity_shards).beta)
+        planes = iter_repair_planes(path, geo, lost)
+        while True:
+            with tracing.stage("read"):
+                chunk = next(planes, None)
+            if chunk is None:
+                break
+            tracing.add("bytes", chunk.nbytes)
+            yield {"file_content": memoryview(chunk.reshape(-1))}

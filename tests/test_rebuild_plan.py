@@ -3,6 +3,7 @@ volume server's VolumeEcShardsRebuild share (storage/ec/plan.py): which
 server rebuilds, which shards it is copied, which it reads, for RS, clay
 and LRC."""
 
+import contextlib
 import json
 import os
 import shutil
@@ -136,21 +137,11 @@ def test_shell_and_server_share_the_read_set(tmp_path, geo, lost):
             if os.path.exists(base + ec.to_ext(s))} == on_disk | set(lost)
 
 
-@pytest.mark.parametrize("kind,flags,copies", [
-    ("rs", "", 6),
-    ("lrc", "-kind lrc -dataShards 12 -parityShards 4 -lrcLocals 2", 4),
-    ("clay", "-kind clay", 9),
-])
-def test_ec_rebuild_verb_copies_only_the_plan(tmp_path, kind, flags,
-                                              copies):
-    """`ec.rebuild` of one lost shard through the shell verb and the
-    volume-server RPCs on four servers: it copies only the part of the
-    read set the rebuilder lacks (LRC 4 of its 6-shard group, RS k minus
-    the rebuilder's 4, clay every survivor it lacks), regenerates only
-    the lost shard, removes its copies, mounts no shard twice, and every
-    blob reads back."""
+@contextlib.contextmanager
+def _sealed_cluster(tmp_path, flags):
+    """A four-server SimCluster with one volume of six blobs sealed by
+    `ec.encode {flags}`: (cluster, env, vid, {fid: payload})."""
     from seaweedfs_tpu import operation, shell
-    from seaweedfs_tpu.shell.command_ec import collect_ec_shard_map
     from seaweedfs_tpu.testing import SimCluster
 
     with SimCluster(volume_servers=4, base_dir=str(tmp_path)) as c:
@@ -166,20 +157,73 @@ def test_ec_rebuild_verb_copies_only_the_plan(tmp_path, kind, flags,
         shell.run_command(env, "lock")
         shell.run_command(env, f"ec.encode -volumeId {vid} {flags}".strip())
         c.sync_heartbeats()
-        n = 16 if kind == "lrc" else 14
-        lost = 0
+        yield c, env, vid, blobs
+
+
+def _lose(c, env, vid, lost):
+    for shard in lost:
         holder = next(vs for vs in c.volume_servers
                       if any(os.path.exists(os.path.join(
-                          d.directory, f"{vid}.ec{lost:02d}"))
+                          d.directory, f"{vid}.ec{shard:02d}"))
                           for d in vs.store.locations))
         client = env.volume_server(holder.grpc_address)
         client.call("VolumeEcShardsUnmount",
-                    {"volume_id": vid, "shard_ids": [lost]})
+                    {"volume_id": vid, "shard_ids": [shard]})
         client.call("VolumeEcShardsDelete", {"volume_id": vid,
                                              "collection": "",
-                                             "shard_ids": [lost]})
-        c.sync_heartbeats()
+                                             "shard_ids": [shard]})
+    c.sync_heartbeats()
+
+
+def _spy_copies(monkeypatch) -> list[dict]:
+    """Every VolumeEcShardsCopy request the servers built after this
+    call receive (the handler is bound when a server starts)."""
+    from seaweedfs_tpu.volume_server.server import VolumeServer
+    seen: list[dict] = []
+    orig = VolumeServer._rpc_ec_copy
+
+    def spy(self, req):
+        seen.append(dict(req))
+        return orig(self, req)
+    monkeypatch.setattr(VolumeServer, "_rpc_ec_copy", spy)
+    return seen
+
+
+def _copy_bytes(c) -> int:
+    return sum(sp.get("bytes", 0) for vs in c.volume_servers
+               for sp in vs.tracer.snapshot()
+               if sp["name"] == "VolumeServer/VolumeEcShardsCopy")
+
+
+@pytest.mark.parametrize("kind,flags,copies", [
+    ("rs", "", 6),
+    ("lrc", "-kind lrc -dataShards 12 -parityShards 4 -lrcLocals 2", 4),
+    ("clay", "-kind clay", 9),
+])
+def test_ec_rebuild_verb_copies_only_the_plan(tmp_path, kind, flags,
+                                              copies, monkeypatch):
+    """`ec.rebuild` of one lost shard through the shell verb and the
+    volume-server RPCs on four servers: it copies only the part of the
+    read set the rebuilder lacks (LRC 4 of its 6-shard group, RS k minus
+    the rebuilder's 4, clay every survivor it lacks), regenerates only
+    the lost shard, removes its copies, mounts no shard twice, and every
+    blob reads back.  RS and LRC copy whole shards; clay, the helpers'
+    repair planes alone, a quarter of each."""
+    from seaweedfs_tpu import shell
+    from seaweedfs_tpu.shell.command_ec import collect_ec_shard_map
+
+    requests = _spy_copies(monkeypatch)
+    with _sealed_cluster(tmp_path, flags) as (c, env, vid, blobs):
+        n = 16 if kind == "lrc" else 14
+        lost = 0
+        _lose(c, env, vid, [lost])
+        shard_size = os.path.getsize(next(
+            os.path.join(d.directory, f"{vid}.ec01")
+            for vs in c.volume_servers for d in vs.store.locations
+            if os.path.exists(os.path.join(d.directory, f"{vid}.ec01"))))
         before = collect_ec_shard_map(env.topology())[vid]
+        del requests[:]
+        sealed_bytes = _copy_bytes(c)
         out = json.loads(shell.run_command(
             env, f"ec.rebuild -volumeId {vid}"))["rebuilt"][0]
         assert out["rebuilt"] == [lost]
@@ -187,9 +231,17 @@ def test_ec_rebuild_verb_copies_only_the_plan(tmp_path, kind, flags,
         assert not set(out["copied"]) & set(before[out["rebuilder"]])
         if kind == "rs":
             assert copies == 10 - len(before[out["rebuilder"]])
+        planes = kind == "clay"
+        assert [r.get("repair_planes_of") for r in requests] \
+            == [lost if planes else None] * len(requests)
+        assert _copy_bytes(c) - sealed_bytes \
+            == copies * shard_size // (4 if planes else 1)
         stats = out["rebuild_stats"]
         assert stats["plan_kind"] == {"rs": "rs-full", "lrc": "local",
                                       "clay": "clay-plane"}[kind]
+        if planes:
+            assert stats["copy"] == "planes"
+            assert stats["helpers_from_planes"] == copies
         (span,) = [sp for vs in c.volume_servers
                    for sp in vs.tracer.snapshot()
                    if sp["name"] == "VolumeServer/VolumeEcShardsRebuild"]
@@ -206,5 +258,48 @@ def test_ec_rebuild_verb_copies_only_the_plan(tmp_path, kind, flags,
                  for s in range(n) if os.path.exists(
                      os.path.join(d.directory, f"{vid}.ec{s:02d}"))]
         assert sorted(files) == list(range(n)), "a temporary copy stayed"
+        assert not [p for vs in c.volume_servers
+                    for d in vs.store.locations
+                    for p in os.listdir(d.directory) if ".planes" in p]
+        for fid, payload in blobs.items():
+            assert c.read(fid) == payload
+
+
+def test_two_clay_losses_copy_whole_shards(tmp_path, monkeypatch):
+    """Two lost clay shards decode from k whole survivors
+    ("clay-decode"): the copy requests carry no plane field and move
+    whole shards."""
+    from seaweedfs_tpu import shell
+
+    requests = _spy_copies(monkeypatch)
+    with _sealed_cluster(tmp_path, "-kind clay") as (c, env, vid, blobs):
+        shards = {}
+        for vs in c.volume_servers:
+            for d in vs.store.locations:
+                for s in (0, 13):
+                    p = os.path.join(d.directory, f"{vid}.ec{s:02d}")
+                    if os.path.exists(p):
+                        with open(p, "rb") as f:
+                            shards[s] = f.read()
+        _lose(c, env, vid, [0, 13])
+        del requests[:]
+        sealed_bytes = _copy_bytes(c)
+        out = json.loads(shell.run_command(
+            env, f"ec.rebuild -volumeId {vid}"))["rebuilt"][0]
+        assert out["rebuilt"] == [0, 13]
+        assert out["rebuild_stats"]["plan_kind"] == "clay-decode"
+        assert out["rebuild_stats"]["copy"] == "whole"
+        assert requests and all("repair_planes_of" not in r
+                                for r in requests)
+        assert _copy_bytes(c) - sealed_bytes \
+            == len(out["copied"]) * len(shards[0])
+        for s, want in shards.items():
+            p = next(os.path.join(d.directory, f"{vid}.ec{s:02d}")
+                     for vs in c.volume_servers for d in vs.store.locations
+                     if os.path.exists(os.path.join(d.directory,
+                                                    f"{vid}.ec{s:02d}")))
+            with open(p, "rb") as f:
+                assert f.read() == want
+        c.sync_heartbeats()
         for fid, payload in blobs.items():
             assert c.read(fid) == payload
