@@ -25,17 +25,18 @@ multiplies than the flat generator (VERDICT r3 weak #2).  Both paths are
 bit-exact equal (tests/test_clay_structured.py proves structured ==
 flat == ops/clay.py oracle byte-for-byte).
 
-Executors: a jitted XLA path (gathers are static permutations, the
-constant GF multiplies lower to eight select-xors, the matmul rides the
-same bit-plane MXU engine as RS) and a numpy/native path for CPU hosts.
-Everything is byte-axis data parallel, so the jax executor also runs
+Executors: the fused Pallas kernels on a TPU (encode_device_fused,
+repair_device_fused: the three steps per tile in VMEM), a jitted XLA
+path elsewhere (gathers are static permutations, the constant GF
+multiplies lower to eight select-xors, the matmul rides the same
+bit-plane engine as RS) and a numpy/native path for CPU hosts.
+Everything is byte-axis data parallel, so the device executors also run
 under shard_map for multi-chip hosts (parallel/mesh_codec wiring).
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import numpy as np
 
@@ -181,66 +182,28 @@ def _layer_mds_matmul(k: int, m: int, u, k0: int):
 
 
 def _use_pallas_engine() -> bool:
-    """ONE gate for 'run the layer-MDS matmul on the Pallas kernel':
-    a TPU exists and the operator has not pinned the XLA engine (a
-    'jax' pin must reach the clay window paths too, for debugging a
-    suspected pallas miscompile) — shared by both matmul entries so
-    the override contract cannot drift between them."""
+    """ONE gate for 'run clay on the Pallas kernels': a TPU exists and
+    the operator has not pinned the XLA engine (a 'jax' pin must reach
+    the clay window paths too, for debugging a suspected pallas
+    miscompile) — shared by the layer-MDS matmul and the fused kernels
+    so the override contract cannot drift between them."""
     from .codec import _tpu_available, ec_backend_override
     return _tpu_available() and ec_backend_override() != "jax"
 
 
-def fused_mode() -> str:
-    """WEED_CLAY_FUSED: ''/'auto' follow _use_pallas_engine(); '0'/'off'
-    pin the tiled path (kill switch); 'interpret' forces the fused
-    kernels through the Pallas interpreter — the CPU/tier-1 handle that
-    makes the fused branch end-to-end testable without a chip."""
-    v = os.environ.get("WEED_CLAY_FUSED", "").strip().lower()
-    if v in ("", "auto"):
-        return "auto"
-    if v in ("0", "off"):
-        return "off"
-    if v == "interpret":
-        return "interpret"
-    raise ValueError(f"WEED_CLAY_FUSED={v!r} (want auto/off/interpret)")
-
-
 def use_fused_engine() -> bool:
     """Gate for the fused clay kernels (encode_device_fused /
-    repair_device_fused running the real VMEM-resident pallas_call)."""
-    mode = fused_mode()
-    if mode == "off":
-        return False
-    if mode == "interpret":
-        return True
+    repair_device_fused): the same rule as the layer-MDS matmul's
+    Pallas engine, so a 'jax' pin sends every clay path through XLA."""
     return _use_pallas_engine()
 
 
-def _layer_mds_matmul_cols(k: int, m: int, u, k0: int):
-    """u [k0, X, 128] -> [m, X, 128] — the column-tiled engine for the
-    relayout-free path (rs_pallas.gf_matmul_bits_pallas_cols consumes
-    the operand's native tiling directly).  X pads up to the kernel's
-    32-sublane block (zero columns encode to zero parity, exactly like
-    the sm path's lane padding).  CPU (tests, shard_map dryrun)
-    flattens for the XLA bit-plane path."""
-    import jax.numpy as jnp
-
-    from . import rs_jax, rs_pallas
-    if _use_pallas_engine():
-        x = u.shape[1]
-        vblock = rs_pallas.cols_vblock_for(k0, m)   # geometry-aware tile
-        pad = (-x) % vblock
-        if pad:
-            u = jnp.pad(u, ((0, 0), (0, pad), (0, 0)))
-        out = rs_pallas.gf_matmul_bits_pallas_cols(
-            jnp.asarray(_r_bits_plane_major(k, m), dtype=jnp.int8), u,
-            vblock=vblock)
-        return out[:, :x] if pad else out
-    k0_, x, lane = u.shape
-    out = rs_jax.gf_matmul_bits(jnp.asarray(_r_bits(k, m)),
-                                u.reshape(k0_, x * lane),
-                                dot_dtype=jnp.int8)
-    return out.reshape(m, x, lane)
+def _interpret() -> bool:
+    """Run the fused kernels through the Pallas interpreter: only where
+    JAX's default backend is not a TPU (tests that force the fused
+    branch on the CPU).  Never true on the chip."""
+    import jax
+    return jax.default_backend() != "tpu"
 
 
 def _pair_swap(arr, q: int, t: int, y: int, off: int = 0):
@@ -257,89 +220,14 @@ def _pair_swap(arr, q: int, t: int, y: int, off: int = 0):
 
 
 def _diag_mask(q: int, t: int, y: int, off: int = 0):
-    """Boolean [q, 1*off, q, .., q, 1, 1] mask of diagonal cells
+    """Boolean [q, 1*off, q, .., q, 1] mask of diagonal cells
     (x == z_y) in the _pair_swap layout (uncoupled == stored there)."""
     import jax
     import jax.numpy as jnp
-    shape = (q,) + (1,) * off + (q,) * t + (1, 1)
+    shape = (q,) + (1,) * off + (q,) * t + (1,)
     x = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     zy = jax.lax.broadcasted_iota(jnp.int32, shape, 1 + off + (t - 1 - y))
     return x == zy
-
-
-def tiled_shape(k: int, m: int, w: int, small: int) -> "tuple | None":
-    """The digit-tiled 5D view [k, n_win, alpha, w_i, 128] of a [k, w]
-    volume slab — a FREE reshape for contiguous host arrays.  None when
-    the window is too narrow for the 128-lane tile (tests' tiny blocks);
-    such calls take the legacy 2D path."""
-    c = code(k, m)
-    w_a = small // c.alpha
-    if w_a % 128 != 0 or w % small != 0:
-        return None
-    return (k, w // small, c.alpha, w_a // 128, 128)
-
-
-def encode_device_tiled(k: int, m: int, data5, *, small: int):
-    """Jittable structured encode over the digit-tiled layout — the
-    RELAYOUT-FREE device path.
-
-    data5 [k, n_win, alpha, w_i, 128] uint8 (tiled_shape's view of the
-    natural [k, W] slab; producers reshape HOST-side where it is free);
-    returns parity [m, n_win, alpha, w_i, 128] (viewable as [m, W]
-    host-side, same argument).
-
-    Round 4's path took [k, W] and paid three hidden HBM round-trips in
-    device reshapes: input [k, W] -> digit axes, the stacked u
-    [k0, ...] -> [k0, W], and the matmul's [k0, W] -> [k0, 8, W/8]
-    retile (each a full copy of its operand — together more traffic
-    than the real work).  Here every reshape either splits/merges axes
-    ABOVE the dense (w_i, 128) minor tile (free) or merges w_i into the
-    sublane axis at its native 32-row tile boundary (also free), so HBM
-    sees only: read data, write+read u, write parity, plus the couple's
-    elementwise pass.  The companion permutation stays an axis swap
-    over the window's q-ary digit axes — never a row gather — and the
-    virtual zero nodes (k..k0) are synthesized per GRID ROW, so only
-    the one partial row pays a concat instead of the whole [k0] slab.
-    Byte-axis parallel throughout — safe under shard_map when the
-    window axis splits on window boundaries."""
-    import jax.numpy as jnp
-
-    c = code(k, m)
-    alpha, k0, q, t = c.alpha, c.k0, c.q, c.t
-    kk, n_win, a, w_i, inner = data5.shape
-    assert (kk, a, inner) == (k, alpha, 128), data5.shape
-    x_cols = n_win * alpha * w_i
-    u_rows = []
-    for y in range(t - 1):
-        lo, hi = y * q, (y + 1) * q
-        if hi <= k:
-            row = data5[lo:hi]
-        elif lo < k:   # the one partial grid row: real nodes + zeros
-            row = jnp.concatenate(
-                [data5[lo:k],
-                 jnp.zeros((hi - k, n_win, alpha, w_i, inner),
-                           jnp.uint8)])
-        else:          # fully virtual row (k0 - k >= q geometries)
-            row = jnp.zeros((q, n_win, alpha, w_i, inner), jnp.uint8)
-        # [x, n_win, z_{t-1}, .., z_0, w_i, inner] — digit z_{t-1} owns
-        # the largest stride of the layer index
-        s = row.reshape(q, n_win, *((q,) * t), w_i, inner)
-        comp = _pair_swap(s, q, t, y, off=1)
-        mask = _diag_mask(q, t, y, off=1)
-        u_rows.append(jnp.where(mask, s,
-                                s ^ _gf_const_mul(GAMMA, comp)))
-    # [k0, n_win, q^t, w_i, 128] -> [k0, X, 128]: merges land exactly on
-    # the u8 (32, 128) tile (alpha and w_i are powers of two with
-    # alpha*w_i >= 32), so the matmul reads it with zero relayout
-    u = jnp.stack(u_rows).reshape(k0, x_cols, inner)
-    u_par = _layer_mds_matmul_cols(k, m, u, k0)
-    # parity row y = t-1: companions pair within the row, axis swap again
-    p = u_par.reshape(q, n_win, *((q,) * t), w_i, inner)
-    comp = _pair_swap(p, q, t, t - 1, off=1)
-    mask = _diag_mask(q, t, t - 1, off=1)
-    c_par = jnp.where(mask, p, _gf_const_mul(
-        int(c._det_inv), p ^ _gf_const_mul(GAMMA, comp)))
-    return c_par.reshape(m, n_win, alpha, w_i, inner)
 
 
 def fused_shape(k: int, m: int, w: int, small: int) -> "tuple | None":
@@ -362,14 +250,12 @@ def encode_device_fused(k: int, m: int, data4, *, small: int):
     data4 [k, n_win, alpha, w_a] uint8 (fused_shape's host-free view of
     the natural [k, W] slab) -> parity [m, n_win, alpha, w_a].
 
-    The tiled path streams the uncoupled operand through HBM (write+read
-    of k0 rows — including the virtual zero rows of the shortened
-    construction) plus an uncoupled-parity round trip: ~(k+2k0+3m)/k
-    bytes of HBM traffic per data byte.  Fused, HBM sees data in and
-    parity out only ((k+m)/k), and the zero rows exist solely as
-    register zeros inside the kernel.  When the fused gate is off (no
-    TPU and not interpret-pinned) this falls back to the tiled path so
-    CPU executors and shard_map dryruns keep working."""
+    HBM sees data in and parity out only ((k+m)/k bytes per data byte):
+    the uncoupled operand and the uncoupled parity never leave VMEM, and
+    the shortened construction's virtual zero rows exist solely as
+    register zeros inside the kernel.  Callers must check
+    use_fused_engine() — there is no XLA fallback for this entry
+    (encode_device is the XLA path)."""
     import jax.numpy as jnp
 
     from . import rs_pallas
@@ -377,16 +263,11 @@ def encode_device_fused(k: int, m: int, data4, *, small: int):
     alpha = c.alpha
     kk, n_win, a, w_a = data4.shape
     assert (kk, a) == (k, alpha), data4.shape
-    if not use_fused_engine():
-        out5 = encode_device_tiled(
-            k, m, data4.reshape(k, n_win, alpha, w_a // 128, 128),
-            small=small)
-        return out5.reshape(m, n_win, alpha, w_a)
     return rs_pallas.clay_fused_encode_pallas(
         jnp.asarray(_r_bits_plane_major(k, m), dtype=jnp.int8), data4,
         q=c.q, t=c.t, gamma=GAMMA, det_inv=int(c._det_inv),
         cb=rs_pallas.clay_fused_cb_for(alpha, w_a),
-        interpret=(fused_mode() == "interpret"))
+        interpret=_interpret())
 
 
 # -- fused single-loss repair ----------------------------------------------
@@ -439,7 +320,7 @@ def repair_device_fused(k: int, m: int, lost: int, x4):
     layer-major layout.  Uncouple of the known rows, the [q, k0] row
     solve, and the out-of-plane back-substitution all stay in VMEM.
     Callers must check use_fused_engine() — there is no XLA fallback
-    for this entry (the tiled/flat repair paths cover that)."""
+    for this entry (the flat repair path covers that)."""
     import jax.numpy as jnp
 
     from . import rs_pallas
@@ -452,7 +333,7 @@ def repair_device_fused(k: int, m: int, lost: int, x4):
         x4, k=k, q=c.q, t=c.t, lost=lost, gamma=GAMMA,
         inv_gamma=inv_gamma,
         cb=rs_pallas.clay_fused_cb_for(beta, w_a),
-        interpret=(fused_mode() == "interpret"))
+        interpret=_interpret())
 
 
 def encode_device(k: int, m: int, data, *, small: int):
@@ -461,11 +342,12 @@ def encode_device(k: int, m: int, data, *, small: int):
     data [k, W] uint8 (W a multiple of the small block) laid out as
     write_ec_files streams it; returns parity [m, W] in the same layout.
 
-    Wide windows route through the relayout-free tiled path
-    (encode_device_tiled) — note the in-jit [k, W] <-> 5D reshapes are
-    real device copies; hot callers (ClayWindowCodec, bench) pass the
-    5D view directly, built host-side for free.  Narrow windows (tests'
-    tiny blocks) keep the legacy digit layout with inner=1."""
+    Windows the fused kernel takes go to encode_device_fused when
+    use_fused_engine() says so (the in-jit [k, W] <-> 4D reshapes are
+    device copies; ClayWindowCodec builds the 4D view host-side and
+    calls the fused entry itself).  Everything else — CPU meshes, narrow
+    windows, a 'jax' pin — runs this XLA path: the companion permutation
+    as digit-axis swaps, the layer-MDS matmul on _layer_mds_matmul."""
     import jax.numpy as jnp
 
     c = code(k, m)
@@ -474,22 +356,14 @@ def encode_device(k: int, m: int, data, *, small: int):
     n_win, w_a = w // small, small // alpha
     shape4 = fused_shape(k, m, w, small)
     if shape4 is not None and use_fused_engine():
-        # the in-jit [k, W] <-> 4D reshapes are device copies; hot
-        # callers build the 4D view host-side and call the fused entry
         return encode_device_fused(
             k, m, data.reshape(shape4), small=small).reshape(m, w)
-    shape5 = tiled_shape(k, m, w, small)
-    if shape5 is not None:
-        return encode_device_tiled(
-            k, m, data.reshape(shape5), small=small).reshape(m, w)
-    inner = 1
-    w_i = w_a
     flat_c = jnp.concatenate(
-        [data.reshape(k, n_win, alpha, w_i, inner),
-         jnp.zeros((k0 - k, n_win, alpha, w_i, inner), jnp.uint8)])
-    # -> [y, x, n_win, z_{t-1}, .., z_0, w_i, inner] (node i = y*q + x;
-    # digit z_{t-1} owns the largest stride of the layer index)
-    v = flat_c.reshape(t - 1, q, n_win, *((q,) * t), w_i, inner)
+        [data.reshape(k, n_win, alpha, w_a),
+         jnp.zeros((k0 - k, n_win, alpha, w_a), jnp.uint8)])
+    # -> [y, x, n_win, z_{t-1}, .., z_0, w_a] (node i = y*q + x; digit
+    # z_{t-1} owns the largest stride of the layer index)
+    v = flat_c.reshape(t - 1, q, n_win, *((q,) * t), w_a)
     u_rows = []
     for y in range(t - 1):
         s = v[y]
@@ -500,7 +374,7 @@ def encode_device(k: int, m: int, data, *, small: int):
     u = jnp.stack(u_rows).reshape(k0, w)
     u_par = _layer_mds_matmul(k, m, u, k0)
     # parity row y = t-1: companions pair within the row, axis swap again
-    p = u_par.reshape(q, n_win, *((q,) * t), w_i, inner)
+    p = u_par.reshape(q, n_win, *((q,) * t), w_a)
     comp = _pair_swap(p, q, t, t - 1, off=1)
     mask = _diag_mask(q, t, t - 1, off=1)
     c_par = jnp.where(mask, p, _gf_const_mul(

@@ -4,9 +4,9 @@ The pure-XLA path (ops/rs_jax.py) materializes the bit-planes tensor
 ([8k, B], 8x the data bytes) in HBM between the unpack and the matmul, so it
 is HBM-bound at roughly 1/20th of peak.  This kernel fuses
 unpack -> MXU matmul -> mod2 -> pack inside VMEM, so HBM traffic is just
-data-in (k*B) + parity-out (m*B) — the codec becomes MXU-bound, which is what
-lets one chip beat the reference's whole-machine AVX2 path
-(klauspost/reedsolomon, driven from weed/storage/erasure_coding/ec_encoder.go:179).
+data-in (k*B) + parity-out (m*B) and the codec is bound by the MXU, not by
+HBM.  The data rides shard-major ([KI, V, B], see gf_matmul_bits_pallas_sm):
+dense on the tiled axes and already in the order .ecNN files are written.
 
 Layout trick: planes are *bit-index-major* ("plane-major"): row j*K + c of the
 plane tensor is bit j of shard-row c.  Unpacking that order is a pure
@@ -42,7 +42,6 @@ from jax.experimental.pallas import tpu as pltpu
 from . import gf256
 
 LANE = 128
-DEFAULT_BLOCK_B = 2048
 
 
 def plane_major_perm(mo: int, ki: int) -> tuple[np.ndarray, np.ndarray]:
@@ -65,65 +64,6 @@ def to_plane_major(bitmat: np.ndarray, mo: int, ki: int) -> np.ndarray:
     assert bitmat.shape == (8 * mo, 8 * ki)
     rows, cols = plane_major_perm(mo, ki)
     return np.ascontiguousarray(bitmat[rows][:, cols])
-
-
-def _gf2_matmul_kernel(mbits_ref, data_ref, out_ref, *, ki: int, mo: int):
-    """One (volume, B-tile) block: out[1, MO, TB] = Mbits ∘GF2∘ data[1, KI, TB].
-
-    All byte twiddling goes through int32: Mosaic has no direct
-    uint8<->bfloat16 casts, and int32 shifts/masks lower cleanly to the VPU.
-    The dot runs in the matrix's dtype — int8 doubles MXU throughput vs
-    bf16 on v5e and is exact here (operands 0/1, partial sums <= 8K <= 2040
-    in the int32 accumulator).
-    """
-    d = data_ref[0].astype(jnp.int32)  # [KI, TB]
-    tb = d.shape[-1]
-    dot_dtype = mbits_ref.dtype
-    acc_dtype = jnp.int32 if dot_dtype == jnp.int8 else jnp.float32
-    in_shifts = jax.lax.broadcasted_iota(jnp.int32, (8, ki, tb), 0)
-    planes = (jnp.broadcast_to(d[None, :, :], (8, ki, tb)) >> in_shifts) & 1
-    planes = planes.reshape(8 * ki, tb).astype(dot_dtype)  # plane-major
-    acc = jnp.dot(mbits_ref[...], planes,
-                  preferred_element_type=acc_dtype)  # [8*MO, TB]
-    bits = acc.astype(jnp.int32) & 1
-    v = bits.reshape(8, mo, tb)
-    out_shifts = jax.lax.broadcasted_iota(jnp.int32, (8, mo, tb), 0)
-    packed = jnp.sum(v << out_shifts, axis=0)
-    out_ref[0] = packed.astype(jnp.uint8)
-
-
-@functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
-def gf_matmul_bits_pallas(mbits_pm: jax.Array, data: jax.Array, *,
-                          block_b: int = DEFAULT_BLOCK_B,
-                          interpret: bool = False) -> jax.Array:
-    """GF(2^8) matmul via fused Pallas kernel.
-
-    mbits_pm: [8*MO, 8*KI] bfloat16 0/1, plane-major (see to_plane_major).
-    data:     [V, KI, B] uint8, B % block_b == 0 (callers pad; zero columns
-              encode to zero parity so padding is benign).
-    returns   [V, MO, B] uint8.
-    """
-    v, ki, b = data.shape
-    mo = mbits_pm.shape[0] // 8
-    assert mbits_pm.shape == (8 * mo, 8 * ki), (mbits_pm.shape, mo, ki)
-    assert b % block_b == 0, f"B={b} must be a multiple of block_b={block_b}"
-    grid = (v, b // block_b)
-    return pl.pallas_call(
-        functools.partial(_gf2_matmul_kernel, ki=ki, mo=mo),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((8 * mo, 8 * ki), lambda i, j: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, ki, block_b), lambda i, j: (i, 0, j),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, mo, block_b), lambda i, j: (i, 0, j),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((v, mo, b), jnp.uint8),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
-    )(mbits_pm, data)
 
 
 SHARD_MAJOR_VBLOCK = 8  # volumes per grid step in the shard-major kernel
@@ -195,48 +135,8 @@ def gf_matmul_bits_pallas_sm(mbits_pm: jax.Array, data: jax.Array, *,
     )(mbits_pm, data)
 
 
-COLS_DEFAULT_VBLOCK = 32  # one full u8 sublane tile per block row
-
-
-@functools.partial(jax.jit, static_argnames=("vblock", "interpret"))
-def gf_matmul_bits_pallas_cols(mbits_pm: jax.Array, data: jax.Array, *,
-                               vblock: int = COLS_DEFAULT_VBLOCK,
-                               interpret: bool = False) -> jax.Array:
-    """Column-tiled layout: data [KI, X, 128] -> parity [MO, X, 128].
-
-    The operand keeps whatever (…, 128)-lane tiling the producer already
-    has — the clay structured path's digit-tiled tensors merge to
-    [k0, X, 128] as a FREE view (X is a multiple of the 32-sublane u8
-    tile), so the matmul consumes them with zero relayout where the
-    2D SM form cost two full HBM round-trips ([k0, W] -> [k0, 8, W/8]
-    is a retile copy on device).  Same kernel math as the shard-major
-    variant; block = (KI, vblock, 128) = 4096 columns at vblock 32."""
-    ki, x, lane = data.shape
-    mo = mbits_pm.shape[0] // 8
-    assert lane == LANE, f"last axis must be {LANE}, got {lane}"
-    assert mbits_pm.shape == (8 * mo, 8 * ki)
-    assert x % vblock == 0, f"X={x} must be a multiple of {vblock}"
-    grid = (x // vblock,)
-    return pl.pallas_call(
-        functools.partial(_gf2_matmul_kernel_sm, ki=ki, mo=mo),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((8 * mo, 8 * ki), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((ki, vblock, LANE), lambda i: (0, i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((mo, vblock, LANE), lambda i: (0, i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((mo, x, LANE), jnp.uint8),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
-        interpret=interpret,
-    )(mbits_pm, data)
-
-
 def _block_vmem_bytes(ki: int, mo: int, lanes: int) -> int:
-    """VMEM bytes one grid step of the SM/cols kernel keeps live for a
+    """VMEM bytes one grid step of the shard-major kernel keeps live for a
     flattened lane count of `lanes` (VB*TB): the double-buffered u8
     operand and output blocks, the int32 unpack of the operand, the int8
     bit-planes and the int32 accumulator.  A budget model, not an exact
@@ -268,21 +168,6 @@ def sm_block_b_for(ki: int, mo: int) -> int:
     return b
 
 
-def cols_vblock_for(ki: int, mo: int) -> int:
-    """vblock for the column-tiled kernel — same budget argument as
-    sm_block_b_for: ki <= 16 keeps the swept 32-sublane block (covers
-    clay k0 = 12 and every default RS geometry unchanged); wider operand
-    stacks halve it until the planes + accumulator working set fits the
-    swept envelope, floored at the u8 8-sublane granule."""
-    if ki <= 16:
-        return COLS_DEFAULT_VBLOCK
-    budget = _block_vmem_bytes(16, 8, COLS_DEFAULT_VBLOCK * LANE)
-    v = COLS_DEFAULT_VBLOCK
-    while v > 8 and _block_vmem_bytes(ki, mo, v * LANE) > budget:
-        v //= 2
-    return v
-
-
 def to_sm_layout(arr: np.ndarray) -> np.ndarray:
     """HOST-side relayout [.., S, B] -> shard-major [S, 8*prod(lead), B/8].
 
@@ -310,28 +195,16 @@ def from_sm_layout(out: np.ndarray, lead: tuple, b: int) -> np.ndarray:
     return np.ascontiguousarray(np.moveaxis(flat, 0, -2))
 
 
-def encode_pallas(parity_bits: np.ndarray, data: jax.Array, *,
-                  block_b: int = DEFAULT_BLOCK_B,
-                  interpret: bool = False) -> jax.Array:
-    """data [V, K, B] -> parity [V, M, B]; parity_bits is rs_matrix.parity_bit_matrix."""
-    k = data.shape[-2]
-    m = parity_bits.shape[0] // 8
-    pm = jnp.asarray(to_plane_major(np.asarray(parity_bits), m, k),
-                     dtype=jnp.bfloat16)
-    return gf_matmul_bits_pallas(pm, data, block_b=block_b, interpret=interpret)
-
-
 # -- fused clay kernels -----------------------------------------------------
 #
-# The tiled structured clay path (ops/clay_structured.encode_device_tiled)
-# still streams its intermediate through HBM: data in (k rows), uncoupled
-# operand out+in (k0 rows — including the synthesized virtual zero rows of
-# the shortened construction), parity out+couple pass (3m rows) — about
-# (k + 2*k0 + 3*m)/k bytes of HBM traffic per data byte (~4.6x for
-# (10,4)).  These kernels do uncouple -> layer-MDS matmul -> couple per
-# batch tile entirely in VMEM: HBM sees data in and parity out, (k+m)/k
-# (~1.4x) — which is what moves the clay encode from the tiled path's
-# ~15.5 GB/s toward the 2D SM kernel's ~18 GB/s operand roofline.
+# The clay encode is three steps: uncouple, the [m, k0] layer-MDS matmul,
+# couple.  Run as separate device ops, the uncoupled operand (k0 rows,
+# including the virtual zero rows of the shortened construction) and the
+# uncoupled parity each make a round trip through HBM.  These kernels do
+# all three steps per batch tile in VMEM, so HBM sees data in and parity
+# out only — (k+m)/k bytes per data byte — and the zero rows exist only as
+# register zeros.  The single-loss repair kernel does the same for the
+# uncouple, the [q, k0] row solve and the out-of-plane back-substitution.
 #
 # Everything clay-specific (grid geometry q x t, coupling constants) comes
 # in as static kwargs so this module stays free of clay imports; the

@@ -150,37 +150,17 @@ class ClayWindowCodec:
             shape4 = clay_structured.fused_shape(self.k, self.m, W,
                                                  small)
             if shape4 is not None and clay_structured.use_fused_engine():
-                # fully fused path: uncouple + layer-MDS + couple in one
-                # pallas_call, VMEM-resident (rs_pallas); the 4D view is
-                # a FREE host reshape both ways
-                fn = _clay_device_fn_fused(self.k, self.m, small,
-                                           clay_structured.fused_mode())
-                dev = fn(jnp.asarray(
-                    np.ascontiguousarray(data).reshape(shape4)))
-
-                def fetch():
-                    return np.asarray(jax.device_get(dev)) \
-                        .reshape(self.m, W)
-                return fetch
-            shape5 = clay_structured.tiled_shape(self.k, self.m, W,
-                                                 small)
-            if shape5 is not None:
-                # relayout-free fast path: the 5D digit-tiled view is a
-                # FREE host reshape both ways; the device never pays a
-                # retile copy (clay_structured.encode_device_tiled)
-                fn = _clay_device_fn_tiled(self.k, self.m, small)
-                dev = fn(jnp.asarray(
-                    np.ascontiguousarray(data).reshape(shape5)))
-
-                def fetch():
-                    return np.asarray(jax.device_get(dev)) \
-                        .reshape(self.m, W)
-                return fetch
-            fn = _clay_device_fn(self.k, self.m, small)
-            dev = fn(jnp.asarray(data))
+                # uncouple + layer-MDS + couple in one VMEM-resident
+                # pallas_call (rs_pallas); the 4D view is a FREE host
+                # reshape both ways
+                dev = _clay_device_fn_fused(self.k, self.m, small)(
+                    jnp.asarray(np.ascontiguousarray(data).reshape(shape4)))
+            else:
+                dev = _clay_device_fn(self.k, self.m, small)(
+                    jnp.asarray(data))
 
             def fetch():
-                return np.asarray(jax.device_get(dev))
+                return np.asarray(jax.device_get(dev)).reshape(self.m, W)
             return fetch
         alpha = self.code.alpha
         win_a = small // alpha
@@ -205,18 +185,7 @@ def _clay_device_fn(k: int, m: int, small: int):
 
 
 @functools.lru_cache(maxsize=8)
-def _clay_device_fn_tiled(k: int, m: int, small: int):
-    import jax
-
-    from ...ops import clay_structured
-    return jax.jit(functools.partial(
-        clay_structured.encode_device_tiled, k, m, small=small))
-
-
-@functools.lru_cache(maxsize=8)
-def _clay_device_fn_fused(k: int, m: int, small: int, mode: str):
-    # keyed by fused_mode so a WEED_CLAY_FUSED flip retraces instead of
-    # serving a stale interpret/compiled closure
+def _clay_device_fn_fused(k: int, m: int, small: int):
     import jax
 
     from ...ops import clay_structured
@@ -225,7 +194,7 @@ def _clay_device_fn_fused(k: int, m: int, small: int, mode: str):
 
 
 @functools.lru_cache(maxsize=32)
-def _clay_repair_fn_fused(k: int, m: int, lost: int, mode: str):
+def _clay_repair_fn_fused(k: int, m: int, lost: int):
     import jax
 
     from ...ops import clay_structured
@@ -335,8 +304,7 @@ def rebuild_clay(base_path: str, geo: EcGeometry, plan: RepairPlan,
                     import jax
                     import jax.numpy as jnp
                     fn = _clay_repair_fn_fused(
-                        geo.data_shards, geo.parity_shards, lost,
-                        clay_structured.fused_mode())
+                        geo.data_shards, geo.parity_shards, lost)
                     rec = np.asarray(jax.device_get(fn(jnp.asarray(x4))))
                     out.write(rec.tobytes())
                     continue

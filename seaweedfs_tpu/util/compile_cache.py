@@ -3,7 +3,7 @@
 The path is part of the cache key, so a cache that moves never hits.
 `JAX_COMPILATION_CACHE_DIR` wins when the environment sets it; otherwise
 the cache lives at a fixed `<checkout>/.jax_cache`.  Entry points call
-place_compile_cache() once at start (CLI, bench.py, chip_smoke.py);
+place_compile_cache() once at start (CLI, chip_smoke.py);
 nothing calls it on import.
 """
 

@@ -17,8 +17,8 @@ cannot be combined with an outer profiler.
 Continuous (`SamplingProfiler`): a daemon thread walks
 `sys._current_frames()` at ~WEED_PROFILE_HZ (default 100) into bounded
 collapsed-stack counters — always on, a few percent of one core at
-worst, so "where is the GIL wall" is answerable from a live cluster
-instead of BENCH_NOTES folklore.  `GET /debug/profile?seconds=N` diffs
+worst, so "where is the GIL wall" is answerable from a live
+cluster.  `GET /debug/profile?seconds=N` diffs
 the counters over an N-second window and serves flamegraph-ready
 collapsed lines (`a;b;c 12` — pipe straight into flamegraph.pl).  The
 sampler also estimates GIL/scheduler contention from sample-interval

@@ -807,8 +807,8 @@ class VolumeServer:
         extended frame ('X') carries replicate/compressed/ttl, so
         replication fan-out and filer ttl'd or pre-gzipped chunk
         uploads ride frames too.  Skipping the Request/Response
-        wrapping and its twelve per-op query-string parses halved the
-        server-side cost on 1KB writes (BENCH_NOTES.md).
+        wrapping and its twelve per-op query-string parses is what
+        the frame saves on every 1KB write.
         -> (size, etag); every avoidable per-op allocation matters
         here: the jwt check reuses the parsed needle key, and the
         fan-out work is built only when replicas actually exist."""

@@ -1,10 +1,9 @@
 """Process-sharded volume data plane — N worker processes behind one
 logical volume server (ISSUE 12).
 
-Every smallfile number before this change was one shared Python core:
-BENCH_NOTES pins the GIL as the wall (~120-Python-call/op floor) while
-the reference hit 47k reads/s with Go across 4 cores.  The unlock is
-horizontal: shard the serving plane across real OS processes so each
+One Python process serves every small-file op on one core, with the
+GIL as its wall, where the Go reference spreads over every core.  The
+unlock is horizontal: shard the serving plane across real OS processes so each
 worker owns a core, and keep the cluster's view of the node unchanged.
 
 Architecture

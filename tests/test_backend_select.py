@@ -135,7 +135,7 @@ def test_clay_window_codec_takes_the_device_path_on_tpu(monkeypatch):
     import seaweedfs_tpu.storage.ec.codes as codes_mod
     from seaweedfs_tpu.storage.ec.layout import EcGeometry
     _mock_platform(monkeypatch, tpu=True)
-    monkeypatch.setenv("WEED_CLAY_FUSED", "off")
+    monkeypatch.delenv("WEED_EC_BACKEND", raising=False)
     monkeypatch.setattr(codes_mod, "_multi_device", lambda: False)
     calls = []
 
@@ -144,7 +144,7 @@ def test_clay_window_codec_takes_the_device_path_on_tpu(monkeypatch):
             calls.append(x.shape)
             return np.zeros((m,) + tuple(x.shape[1:]), np.uint8)
         return run
-    monkeypatch.setattr(codes_mod, "_clay_device_fn_tiled", fake_fn)
+    monkeypatch.setattr(codes_mod, "_clay_device_fn_fused", fake_fn)
     geo = EcGeometry(10, 4, small_block_size=256 * 128, code_kind="clay")
     out = codes_mod.ClayWindowCodec(geo).encode(
         np.zeros((10, 256 * 128), np.uint8))

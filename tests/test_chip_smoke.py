@@ -19,6 +19,7 @@ import pytest
 
 import chip_smoke as cs
 import seaweedfs_tpu.ops.codec as codec_mod
+from seaweedfs_tpu.ops import clay_structured
 from seaweedfs_tpu.parallel import mesh_codec
 from seaweedfs_tpu.testing import SimCluster
 
@@ -32,9 +33,9 @@ def cluster():
 def test_rs_and_clay_phases(cluster, monkeypatch):
     # one-device host: the single-chip codec, as on the chip
     monkeypatch.setattr(mesh_codec, "multi_device_host", lambda: False)
-    # fused clay kernels through the interpreter; the window codec's
-    # device gate must let them run on this CPU host
-    monkeypatch.setenv("WEED_CLAY_FUSED", "interpret")
+    # fused clay kernels (through the interpreter on this CPU host); the
+    # window codec's device gate must let them run here
+    monkeypatch.setattr(clay_structured, "use_fused_engine", lambda: True)
     monkeypatch.delenv("WEED_EC_BACKEND", raising=False)
     monkeypatch.setattr(codec_mod, "device_compute_ok", lambda: True)
     backend = "rs_" + codec_mod.resolve_backend()

@@ -1,10 +1,10 @@
-"""Fused clay VMEM kernel == tiled == flat generator == numpy oracle,
+"""Fused clay VMEM kernel == XLA structured == flat generator == numpy oracle,
 byte for byte — encode AND single-loss repair, across geometries,
 window widths and loss masks.
 
 The fused kernels (rs_pallas._clay_fused_encode_kernel / _repair_kernel)
 are the production TPU hot path; on this CPU suite they run through the
-Pallas interpreter (WEED_CLAY_FUSED=interpret), so tier-1 proves the
+Pallas interpreter (JAX's backend is not a TPU), so tier-1 proves the
 kernel's own math — uncouple, layer-MDS bit-plane matmul, couple, the
 virtual-zero-row synthesis and the out-of-plane back-substitution —
 without a chip.  Any divergence is data corruption: np.array_equal
@@ -22,24 +22,27 @@ GEOMETRIES = [(4, 2), (6, 3), (10, 4)]
 
 
 def _interpret(monkeypatch):
-    """Force the fused kernels through the Pallas interpreter and make
-    the gates deterministic regardless of the outer WEED_EC_BACKEND arm
-    (tools/check.sh runs this file twice).  device_compute_ok is pinned
-    True so the device branches run on this CPU host — the standing
-    idiom from test_clay_structured.py."""
+    """Open the fused gate on this CPU host (the kernels then run through
+    the Pallas interpreter) and make the gates deterministic regardless
+    of the outer WEED_EC_BACKEND arm (tools/check.sh runs this file
+    twice).  device_compute_ok is pinned True so the device branches run
+    on this CPU host — the standing idiom from test_clay_structured.py."""
     import seaweedfs_tpu.ops.codec as codec_mod
-    monkeypatch.setenv("WEED_CLAY_FUSED", "interpret")
+    monkeypatch.setattr(clay_structured, "use_fused_engine", lambda: True)
     monkeypatch.delenv("WEED_EC_BACKEND", raising=False)
     monkeypatch.setattr(codec_mod, "device_compute_ok", lambda: True)
+
+
+def _gate_off(monkeypatch):
+    monkeypatch.setattr(clay_structured, "use_fused_engine", lambda: False)
 
 
 # -- encode -----------------------------------------------------------------
 
 @pytest.mark.parametrize("k,m", GEOMETRIES)
 def test_fused_encode_bit_identity(k, m, monkeypatch):
-    """fused == tiled == flat generator == numpy oracle."""
+    """fused == XLA structured (gate off) == flat generator == oracle."""
     import jax.numpy as jnp
-    _interpret(monkeypatch)
     c = clay_matrix.code(k, m)
     small = c.alpha * 128
     n_win = 2
@@ -53,11 +56,10 @@ def test_fused_encode_bit_identity(k, m, monkeypatch):
         k, m, jnp.asarray(data.reshape(shape4)), small=small)
     ).reshape(m, W)
     assert np.array_equal(fused, oracle)
-    tiled = np.asarray(clay_structured.encode_device_tiled(
-        k, m, jnp.asarray(data.reshape(
-            clay_structured.tiled_shape(k, m, W, small))), small=small)
-    ).reshape(m, W)
-    assert np.array_equal(fused, tiled)
+    _gate_off(monkeypatch)
+    xla = np.asarray(clay_structured.encode_device(
+        k, m, jnp.asarray(data), small=small))
+    assert np.array_equal(fused, xla)
     win_a = small // c.alpha
     flat_in = np.ascontiguousarray(
         data.reshape(k, n_win, c.alpha, win_a).transpose(0, 2, 1, 3)
@@ -100,26 +102,33 @@ def test_fused_shape_gates_narrow_windows():
         == (k, 2, c.alpha, 128)
 
 
-def test_fused_mode_env(monkeypatch):
-    monkeypatch.delenv("WEED_CLAY_FUSED", raising=False)
-    assert clay_structured.fused_mode() == "auto"
-    monkeypatch.setenv("WEED_CLAY_FUSED", "off")
-    assert clay_structured.fused_mode() == "off"
-    assert not clay_structured.use_fused_engine()
-    monkeypatch.setenv("WEED_CLAY_FUSED", "interpret")
-    assert clay_structured.fused_mode() == "interpret"
-    assert clay_structured.use_fused_engine()
-    monkeypatch.setenv("WEED_CLAY_FUSED", "bogus")
-    with pytest.raises(ValueError):
-        clay_structured.fused_mode()
+@pytest.mark.parametrize("tpu,pin,want", [
+    (True, None, True),        # TPU present
+    (False, None, False),      # TPU absent
+    (True, "jax", False),      # TPU present, XLA engine pinned
+], ids=["tpu", "no-tpu", "jax-pin"])
+def test_fused_gate_follows_pallas_engine(tpu, pin, want, monkeypatch):
+    """The fused gate is _use_pallas_engine(): no env var of its own."""
+    import seaweedfs_tpu.ops.codec as codec_mod
+    monkeypatch.setattr(codec_mod, "_tpu_available", lambda: tpu)
+    if pin is None:
+        monkeypatch.delenv("WEED_EC_BACKEND", raising=False)
+    else:
+        monkeypatch.setenv("WEED_EC_BACKEND", pin)
+    assert clay_structured._use_pallas_engine() is want
+    assert clay_structured.use_fused_engine() is want
 
 
 def test_fused_fallback_matches_tiled(monkeypatch):
-    """With the fused engine off, encode_device_fused must route through
-    the tiled path (the CPU/shard_map fallback contract) and still
-    return oracle bytes."""
+    """With the fused gate off, encode_device must take its XLA path on
+    windows the fused kernel would accept (the CPU/shard_map contract:
+    the fused entry is never called) and still return oracle bytes —
+    the same bytes the fused kernel gives."""
     import jax.numpy as jnp
-    monkeypatch.setenv("WEED_CLAY_FUSED", "off")
+    _gate_off(monkeypatch)
+
+    def no_fused(*a, **kw):
+        raise AssertionError("fused kernel called with the gate off")
     k, m = 4, 2
     c = clay_matrix.code(k, m)
     small = c.alpha * 128
@@ -127,10 +136,14 @@ def test_fused_fallback_matches_tiled(monkeypatch):
     rng = np.random.default_rng(1)
     data = rng.integers(0, 256, (k, W), dtype=np.uint8)
     shape4 = clay_structured.fused_shape(k, m, W, small)
-    out = np.asarray(clay_structured.encode_device_fused(
+    fused = np.asarray(clay_structured.encode_device_fused(
         k, m, jnp.asarray(data.reshape(shape4)), small=small)
     ).reshape(m, W)
+    monkeypatch.setattr(clay_structured, "encode_device_fused", no_fused)
+    out = np.asarray(clay_structured.encode_device(
+        k, m, jnp.asarray(data), small=small))
     assert np.array_equal(out, natural_layout_parity(k, m, data, small))
+    assert np.array_equal(out, fused)
 
 
 # -- single-loss repair -----------------------------------------------------
@@ -210,8 +223,8 @@ def _write_clay_volume(tmp_path, name, geo, payload):
 
 
 def test_rebuild_clay_fused_branch(tmp_path, monkeypatch):
-    """rebuild_ec_files with the fused engine pinned to interpret runs
-    the fused single-loss branch end to end (memmap plane gather ->
+    """rebuild_ec_files with the fused gate open (interpreted on this
+    host) runs the fused single-loss branch end to end (memmap plane gather ->
     pallas_call -> shard write) and regenerates byte-identical shards."""
     import seaweedfs_tpu.storage.ec as ec
     c = clay_matrix.code(10, 4)
@@ -411,13 +424,10 @@ def test_block_pickers_geometry_aware():
     # default geometries keep their swept tiles — no behavior change
     assert rs_pallas.sm_block_b_for(10, 4) == rs_pallas.SM_DEFAULT_BLOCK_B
     assert rs_pallas.sm_block_b_for(16, 8) == rs_pallas.SM_DEFAULT_BLOCK_B
-    assert rs_pallas.cols_vblock_for(12, 4) == rs_pallas.COLS_DEFAULT_VBLOCK
     # wide stripes shrink to hold the VMEM working set constant
     wide = rs_pallas.sm_block_b_for(28, 4)
     assert 128 <= wide < rs_pallas.SM_DEFAULT_BLOCK_B
     assert wide & (wide - 1) == 0      # power of two (tile alignment)
-    vb = rs_pallas.cols_vblock_for(56, 8)
-    assert 8 <= vb < rs_pallas.COLS_DEFAULT_VBLOCK
     # RSCodec's default block follows the picker
     from seaweedfs_tpu.ops.codec import RSCodec
     assert RSCodec(28, 4, backend="numpy").block_b == wide

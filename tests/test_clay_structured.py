@@ -81,46 +81,40 @@ def test_window_codec_uses_structured_path(tmp_path):
     assert np.array_equal(got, want)
 
 
-def test_tiled_device_path_matches_oracle():
-    """encode_device_tiled (the relayout-free production path) is
-    byte-identical to the numpy oracle and to the legacy 2D entry for
-    windows wide enough for the 128-lane tile."""
+def test_tiled_device_path_matches_oracle(monkeypatch):
+    """encode_device's XLA path (the fused gate off: CPU meshes, a 'jax'
+    pin) on windows wide enough for the 128-lane tile is byte-identical
+    to the numpy oracle, and the window codec's device branch gives the
+    same bytes on the same windows."""
     import jax.numpy as jnp
+
+    import seaweedfs_tpu.ops.codec as codec_mod
+    from seaweedfs_tpu.storage.ec.codes import ClayWindowCodec
+    from seaweedfs_tpu.storage.ec.layout import EcGeometry
+    monkeypatch.setattr(clay_structured, "use_fused_engine", lambda: False)
+    monkeypatch.setattr(codec_mod, "device_compute_ok", lambda: True)
     k, m = 10, 4
     c = clay_matrix.code(k, m)
-    small = c.alpha * 128           # the narrowest tiled window
+    small = c.alpha * 128           # the narrowest fused-size window
     n_win = 3
     W = n_win * small
     rng = np.random.default_rng(11)
     data = rng.integers(0, 256, (k, W), dtype=np.uint8)
-    shape5 = clay_structured.tiled_shape(k, m, W, small)
-    assert shape5 == (k, n_win, c.alpha, 1, 128)
-    got5 = np.asarray(clay_structured.encode_device_tiled(
-        k, m, jnp.asarray(data.reshape(shape5)), small=small))
-    got = got5.reshape(m, W)
-    via_2d = np.asarray(clay_structured.encode_device(
+    got = np.asarray(clay_structured.encode_device(
         k, m, jnp.asarray(data), small=small))
-    np.testing.assert_array_equal(got, via_2d)
     # oracle construction shared with the real-chip gate
     from clay_oracle import natural_layout_parity
     np.testing.assert_array_equal(
         got, natural_layout_parity(k, m, data, small))
-
-
-def test_tiled_shape_gates_narrow_windows():
-    k, m = 10, 4
-    c = clay_matrix.code(k, m)
-    assert clay_structured.tiled_shape(k, m, c.alpha * 16 * 4,
-                                       c.alpha * 16) is None
-    assert clay_structured.tiled_shape(
-        k, m, c.alpha * 256 * 2, c.alpha * 256) \
-        == (k, 2, c.alpha, 2, 128)
+    geo = EcGeometry(k, m, large_block_size=1 << 20,
+                     small_block_size=small, code_kind="clay")
+    np.testing.assert_array_equal(ClayWindowCodec(geo).encode(data), got)
 
 
 def test_window_codec_tiled_path_round_trips(tmp_path, monkeypatch):
-    """The production window codec rides the tiled (relayout-free) device
-    path for real-sized small blocks; its shard files must be
-    byte-identical to the host path's and still rebuild."""
+    """The window codec's device branch with the fused gate off (the
+    jitted XLA encode_device) on real-sized small blocks: its shard
+    files must be byte-identical to the host path's and still rebuild."""
     import os
 
     import seaweedfs_tpu.ops.codec as codec_mod
@@ -131,6 +125,7 @@ def test_window_codec_tiled_path_round_trips(tmp_path, monkeypatch):
     rng = np.random.default_rng(5)
     payload = rng.integers(0, 256, 3 * geo.small_row_size() + 999,
                            dtype=np.uint8).tobytes()
+    monkeypatch.setattr(clay_structured, "use_fused_engine", lambda: False)
     bases = {}
     for mode in ("host", "tiled"):
         d = tmp_path / mode
@@ -139,7 +134,7 @@ def test_window_codec_tiled_path_round_trips(tmp_path, monkeypatch):
         with open(base + ".dat", "wb") as f:
             f.write(payload)
         # 'tiled' forces the device branch (here: CPU jax executor) so
-        # the codec's tiled wiring itself is what runs
+        # the codec's device wiring itself is what runs
         monkeypatch.setattr(codec_mod, "device_compute_ok",
                             lambda: mode == "tiled")
         ec.write_ec_files(base, geo)
@@ -147,7 +142,7 @@ def test_window_codec_tiled_path_round_trips(tmp_path, monkeypatch):
     for i in range(geo.total_shards):
         a = open(bases["host"] + f".ec{i:02d}", "rb").read()
         b = open(bases["tiled"] + f".ec{i:02d}", "rb").read()
-        assert a == b, f"shard {i}: tiled codec path diverges from host"
+        assert a == b, f"shard {i}: device codec path diverges from host"
     os.remove(bases["tiled"] + ".ec03")
     ec.rebuild_ec_files(bases["tiled"], geo)
     assert open(bases["tiled"] + ".ec03", "rb").read() \

@@ -140,7 +140,7 @@ def test_wide_and_cauchy_geometries():
 
 
 def test_shard_major_kernel_interpret():
-    """The shard-major [K, V, B] kernel (the bench fast path) is bit-exact
+    """The shard-major [K, V, B] kernel (the RS production kernel) is bit-exact
     for both int8 and bf16 MXU dtypes (pallas interpreter, no TPU)."""
     import jax.numpy as jnp
     from seaweedfs_tpu.ops import gf256, rs_pallas
@@ -160,50 +160,29 @@ def test_shard_major_kernel_interpret():
             assert np.array_equal(out[:, v, :], want), (dtype, v)
 
 
-def test_cols_kernel_interpret():
-    """The column-tiled [K, X, 128] kernel (the clay relayout-free
-    matmul) is bit-exact vs the gf256 tables (pallas interpreter, no
-    TPU) — including the X padding to the 32-sublane block."""
-    import jax.numpy as jnp
-    from seaweedfs_tpu.ops import gf256, rs_pallas
-    k, m = 12, 4   # clay(10,4)'s k0 x m layer-MDS shape
-    lrng = np.random.default_rng(4)
-    gen = rs_matrix.generator_matrix(k, m)
-    bits = rs_matrix.bit_matrix(gen[k:])
-    pm = jnp.asarray(rs_pallas.to_plane_major(bits, m, k),
-                     dtype=jnp.int8)
-    for x in (32, 96):  # tile-aligned and multi-block
-        d = lrng.integers(0, 256, (k, x, 128), dtype=np.uint8)
-        got = np.asarray(rs_pallas.gf_matmul_bits_pallas_cols(
-            pm, jnp.asarray(d), interpret=True))
-        want = gf256.matmul(gen[k:], d.reshape(k, x * 128)) \
-            .reshape(m, x, 128)
-        assert np.array_equal(got, want)
-
-
-def test_layer_mds_cols_pads_unaligned_x(monkeypatch):
-    """_layer_mds_matmul_cols pads X up to the kernel block (zero
-    columns -> zero parity) instead of handing Mosaic a sub-tile
-    BlockSpec; interpret mode stands in for the TPU."""
+def test_layer_mds_sm_pads_unaligned_width(monkeypatch):
+    """_layer_mds_matmul's shard-major branch pads the width up to the
+    kernel block (zero columns -> zero parity) instead of handing Mosaic
+    a sub-tile BlockSpec; interpret mode stands in for the TPU."""
     import jax.numpy as jnp
     import seaweedfs_tpu.ops.clay_structured as cs
     from seaweedfs_tpu.ops import rs_pallas
     monkeypatch.setattr(cs, "_use_pallas_engine", lambda: True)
-    real = rs_pallas.gf_matmul_bits_pallas_cols
+    real = rs_pallas.gf_matmul_bits_pallas_sm
     monkeypatch.setattr(
-        rs_pallas, "gf_matmul_bits_pallas_cols",
-        lambda pmat, u, vblock=32: real(pmat, u, vblock=vblock,
-                                        interpret=True))
+        rs_pallas, "gf_matmul_bits_pallas_sm",
+        lambda pmat, u, block_b: real(pmat, u, block_b=block_b,
+                                      interpret=True))
     k, m = 4, 2
     k0 = cs.code(k, m).k0
+    n = 24 * 128 + 40      # not a multiple of the 8*block_b block
     lrng = np.random.default_rng(6)
-    u = lrng.integers(0, 256, (k0, 24, 128), dtype=np.uint8)  # X=24
-    got = np.asarray(cs._layer_mds_matmul_cols(k, m,
-                                               jnp.asarray(u), k0))
+    u = lrng.integers(0, 256, (k0, n), dtype=np.uint8)
+    got = np.asarray(cs._layer_mds_matmul(k, m, jnp.asarray(u), k0))
     R = cs.code(k, m).gen[k0:]
     from seaweedfs_tpu.ops import gf256
-    want = gf256.matmul(np.ascontiguousarray(R),
-                        u.reshape(k0, -1)).reshape(m, 24, 128)
+    want = gf256.matmul(np.ascontiguousarray(R), u)
+    assert got.shape == (m, n)
     assert np.array_equal(got, want)
 
 

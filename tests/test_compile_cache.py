@@ -46,7 +46,7 @@ def test_default_dir_is_the_checkout(tmp_path):
             "place_compile_cache as p; p(); import jax; "
             "print(jax.config.jax_compilation_cache_dir)")
     assert _run(code) == want
-    # ... and after (bench.py / chip_smoke.py import JAX first)
+    # ... and after (chip_smoke.py imports JAX first)
     code = ("import jax; from seaweedfs_tpu.util.compile_cache import "
             "place_compile_cache as p; p(); "
             "print(jax.config.jax_compilation_cache_dir)")

@@ -64,23 +64,6 @@ def test_sm_kernel_byte_identity_on_chip():
 
 
 @skip_unless_tpu
-def test_cols_kernel_byte_identity_on_chip():
-    import jax.numpy as jnp
-
-    from seaweedfs_tpu.ops import gf256, rs_matrix, rs_pallas
-    k, m = 12, 4
-    gen = rs_matrix.generator_matrix(k, m)
-    bits = rs_matrix.bit_matrix(gen[k:])
-    pm = jnp.asarray(rs_pallas.to_plane_major(bits, m, k),
-                     dtype=jnp.int8)
-    d = _rng(2).integers(0, 256, (k, 64, 128), dtype=np.uint8)
-    got = np.asarray(rs_pallas.gf_matmul_bits_pallas_cols(
-        pm, jnp.asarray(d)))
-    want = gf256.matmul(gen[k:], d.reshape(k, -1)).reshape(m, 64, 128)
-    np.testing.assert_array_equal(got, want)
-
-
-@skip_unless_tpu
 def test_rscodec_encode_reconstruct_on_chip():
     from seaweedfs_tpu.ops.codec import RSCodec
     codec = RSCodec(10, 4, backend="pallas")
@@ -108,9 +91,9 @@ def test_clay_tiled_encode_on_chip():
     small = c.alpha * 128
     W = 2 * small
     data = _rng(4).integers(0, 256, (k, W), dtype=np.uint8)
-    shape5 = clay_structured.tiled_shape(k, m, W, small)
-    got = np.asarray(clay_structured.encode_device_tiled(
-        k, m, jnp.asarray(data.reshape(shape5)),
+    shape4 = clay_structured.fused_shape(k, m, W, small)
+    got = np.asarray(clay_structured.encode_device_fused(
+        k, m, jnp.asarray(data.reshape(shape4)),
         small=small)).reshape(m, W)
     from clay_oracle import natural_layout_parity
     np.testing.assert_array_equal(

@@ -84,14 +84,6 @@ def test_sm_kernel_compiles(one_chip, k, m, v):
     assert mem.output_size_in_bytes == m * v * 8 * MIB
 
 
-def test_cols_kernel_compiles(one_chip):
-    k0, m = code(10, 4).k0, 4
-    pm = _spec((8 * m, 8 * k0), jnp.int8, one_chip)
-    data = _spec((k0, 64 * 1024, rs_pallas.LANE), jnp.uint8, one_chip)
-    _compile(lambda p, x: rs_pallas.gf_matmul_bits_pallas_cols(
-        p, x, vblock=rs_pallas.cols_vblock_for(k0, m)), pm, data)
-
-
 @pytest.mark.parametrize("k,m", [(10, 4), (16, 8)])
 def test_clay_fused_encode_compiles(one_chip, k, m):
     """alpha = 512 at (16,8): refused at Mosaic's default 16 MiB scoped
@@ -136,6 +128,26 @@ def test_mesh_codec_encode_compiles_for_four_chips(topo):
     mem = compiled.memory_analysis()
     # each device holds a quarter of the operand
     assert mem.argument_size_in_bytes < k * b // 2
+
+
+def test_clay_mesh_encode_compiles_for_four_chips(topo, monkeypatch):
+    """The multi-chip clay encode (mesh_codec._clay_mesh_fn): under
+    shard_map, encode_device hands each device's windows to the fused
+    kernel.  The gate and the interpreter switch follow the chip, so
+    both are set here as a TPU host would have them."""
+    from seaweedfs_tpu.parallel import mesh_codec
+    monkeypatch.setattr(clay_structured, "use_fused_engine", lambda: True)
+    monkeypatch.setattr(clay_structured, "_interpret", lambda: False)
+    mesh = mesh_codec.default_ec_mesh(np.asarray(topo.devices))
+    k, m = 10, 4
+    w = 4 * 8 * MIB                      # 8 windows of 1 MiB per device
+    data = _spec((k, w), jnp.uint8,
+                 NamedSharding(mesh, P(None, ("s", "b"))))
+    fn = mesh_codec._clay_mesh_fn.__wrapped__(mesh, k, m, MIB)
+    compiled = fn.lower(data).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # each device holds a quarter of the windows
+    assert compiled.memory_analysis().argument_size_in_bytes < k * w // 2
 
 
 def test_mesh_codec_reconstruct_compiles_for_four_chips(topo):
