@@ -69,6 +69,11 @@ def clear_tls() -> None:
     POOL.close()
 
 
+def tls_enabled() -> bool:
+    """Whether gRPC runs under mutual TLS (set_tls)."""
+    return _TLS is not None
+
+
 def _channel_credentials():
     ca, cert, key = _TLS.read()
     return grpc.ssl_channel_credentials(

@@ -11,14 +11,17 @@ from __future__ import annotations
 
 import json
 import time
+from concurrent.futures import ThreadPoolExecutor
+from functools import partial
 
 from ..pb.rpc import RpcError
 from ..storage.ec.layout import TOTAL_SHARDS_COUNT, EcGeometry
 from ..storage.ec.plan import RepairPlan, repair_plan
 from ..storage.ec.shard_bits import ShardBits
+from ..util import tracing
 from ..util.weedlog import logger
 from .commands import (CommandEnv, ShellError, command, iter_data_nodes,
-                       node_grpc, parse_flags)
+                       node_grpc, node_tcp, parse_flags)
 
 LOG = logger(__name__)
 
@@ -145,6 +148,39 @@ def _grpc_of_location(topo: dict, url: str) -> str:
     raise ShellError(f"no grpc address for {url}")
 
 
+def _run_per_node(what: str, jobs: dict) -> None:
+    """Run each node's job (node id -> callable) at once, one worker a
+    node, each under the caller's trace, so the RPCs it makes carry the
+    verb's trace id and parent span (parallelCopyEcShardsFromSource
+    command_ec_encode.go:190).  Every job ends before this returns; the
+    first failure in the jobs' order is then raised, the others logged."""
+    if not jobs:
+        return
+    with ThreadPoolExecutor(max_workers=len(jobs)) as pool:
+        futures = {nid: pool.submit(tracing.propagate(job))
+                   for nid, job in jobs.items()}
+    errors = [(nid, e) for nid, f in futures.items()
+              if (e := f.exception()) is not None]
+    for nid, e in errors[1:]:
+        LOG.warning("%s: %s failed too: %s", what, nid, e)
+    if errors:
+        raise errors[0][1]
+
+
+def _most_at_once(spans: list) -> int:
+    """The most of `spans` ((start, end) pairs) open at one time: the
+    verbs' `copy_fanout`, the copies that ran together."""
+    return max((sum(s <= t < e for s, e in spans) for t, _ in spans),
+               default=0)
+
+
+def _tcp_by_id(topo: dict) -> dict:
+    """{node id: its frame port "host:port"} of the nodes that have one;
+    a VolumeEcShardsCopy names its source's as `source_tcp`."""
+    return {dn["id"]: node_tcp(dn) for _, _, dn in iter_data_nodes(topo)
+            if dn.get("tcp_port")}
+
+
 def do_ec_encode(env: CommandEnv, vid: int, collection: str = "",
                  data_shards: int = 0, parity_shards: int = 0,
                  kind: str = "", lrc_locals: int = 0) -> dict:
@@ -159,8 +195,10 @@ def do_ec_encode(env: CommandEnv, vid: int, collection: str = "",
     delete sequence swaps live volume state on several servers, and a
     failure part-way through is a prime suspect for the soak
     SizeMismatchError — the id ties this orchestration to the
-    volume-side swap logs."""
-    from ..util import tracing
+    volume-side swap logs.
+
+    The spread runs every node's copy and mount at once; `copy_fanout`
+    in the output is the most copies that overlapped."""
     tid = tracing.current_trace_id() or tracing.new_trace_id()
     with tracing.trace_scope(tid):
         try:
@@ -212,16 +250,26 @@ def _do_ec_encode_traced(env: CommandEnv, vid: int, tid: str,
         if f"{dn['ip']}:{dn['port']}" == locations[0]["url"] \
                 or dn["id"] == locations[0]["url"]:
             src_id = dn["id"]
-    for node_id, shard_ids in plan.items():
+    src_tcp = _tcp_by_id(topo).get(src_id, "")
+    copying: list = []
+
+    def place(node_id: str, shard_ids: list[int]) -> None:
         target = env.volume_server(grpc_by_id[node_id])
         if node_id != src_id:
+            t0 = time.perf_counter()
             target.call("VolumeEcShardsCopy", {
                 "volume_id": vid, "collection": collection,
                 "shard_ids": shard_ids, "copy_ecx_files": True,
-                "source_data_node": src_grpc}, timeout=3600)
+                "source_data_node": src_grpc, "source_tcp": src_tcp},
+                timeout=3600)
+            copying.append((t0, time.perf_counter()))
         target.call("VolumeEcShardsMount",
                     {"volume_id": vid, "collection": collection,
                      "shard_ids": shard_ids})
+    # every node's copy + mount at once; the source's job is its mount
+    _run_per_node(f"ec.encode volume {vid}",
+                  {nid: partial(place, nid, ids)
+                   for nid, ids in plan.items()})
     # drop non-local shard files from the source, delete original volume
     src = env.volume_server(src_grpc)
     keep = set(plan.get(src_id, []))
@@ -235,7 +283,8 @@ def _do_ec_encode_traced(env: CommandEnv, vid: int, tid: str,
     for loc in locations:
         env.volume_server(_grpc_of_location(topo, loc["url"])).call(
             "VolumeDelete", {"volume_id": vid})
-    return {"volume_id": vid, "distribution": plan}
+    return {"volume_id": vid, "distribution": plan,
+            "copy_fanout": _most_at_once(copying)}
 
 
 def _ec_geometry(env: CommandEnv, vid: int, collection: str,
@@ -273,7 +322,10 @@ def do_ec_rebuild(env: CommandEnv, vid: int, collection: str = "") -> dict:
     only the beta repair planes its repair reads, a quarter of the shard
     for clay(10,4) (`repair_planes_of` on VolumeEcShardsCopy); the
     rebuild reads and removes those plane files, and a failed copy or
-    rebuild has them removed.  Every other plan copies whole shards."""
+    rebuild has them removed.  Every other plan copies whole shards.
+
+    The copies from the sources run at once (`copy_fanout` in the
+    output); the rebuild, and a failure's clean-up, wait for all."""
     topo = env.topology()
     shard_map = collect_ec_shard_map(topo).get(vid, {})
     present = sorted({s for ids in shard_map.values() for s in ids})
@@ -282,7 +334,8 @@ def do_ec_rebuild(env: CommandEnv, vid: int, collection: str = "") -> dict:
     geo = _ec_geometry(env, vid, collection, list(shard_map), grpc_by_id)
     missing = [s for s in range(geo.total_shards) if s not in present]
     if not missing:
-        return {"volume_id": vid, "rebuilt": [], "copied": []}
+        return {"volume_id": vid, "rebuilt": [], "copied": [],
+                "copy_fanout": 0}
     try:
         rebuilder_id, plan, copies = plan_rebuild(geo, missing, shard_map)
     except ValueError as e:
@@ -291,22 +344,30 @@ def do_ec_rebuild(env: CommandEnv, vid: int, collection: str = "") -> dict:
     # a single clay loss copies only the helpers' repair planes
     planes = {"repair_planes_of": missing[0]} \
         if plan.kind == "clay-plane" else {}
-    copied: list[int] = []
+
+    tcp_by_id = _tcp_by_id(topo)
+    copying: list = []
+
+    def copy(node_id: str, take: list[int]) -> None:
+        t0 = time.perf_counter()
+        rebuilder.call("VolumeEcShardsCopy", {
+            "volume_id": vid, "collection": collection,
+            "shard_ids": take, "copy_ecx_files": False,
+            "source_data_node": grpc_by_id[node_id],
+            "source_tcp": tcp_by_id.get(node_id, ""), **planes},
+            timeout=3600)
+        copying.append((t0, time.perf_counter()))
     try:
-        for node_id, take in copies.items():
-            rebuilder.call("VolumeEcShardsCopy", {
-                "volume_id": vid, "collection": collection,
-                "shard_ids": take, "copy_ecx_files": False,
-                "source_data_node": grpc_by_id[node_id], **planes},
-                timeout=3600)
-            copied += take
+        _run_per_node(f"ec.rebuild volume {vid}",
+                      {nid: partial(copy, nid, take)
+                       for nid, take in copies.items()})
         out = rebuilder.call("VolumeEcShardsRebuild",
                              {"volume_id": vid, "collection": collection,
                               "shard_ids": missing}, timeout=3600)
     except RpcError:
         if planes:
             # the rebuild removes the plane files it read; after a
-            # failure they would lie there unused
+            # failure (every copy has ended) they would lie there unused
             try:
                 rebuilder.call("VolumeEcShardsDelete", {
                     "volume_id": vid, "collection": collection,
@@ -317,6 +378,7 @@ def do_ec_rebuild(env: CommandEnv, vid: int, collection: str = "") -> dict:
                             "%s: %s", vid, rebuilder_id, e)
         raise
     rebuilt = out.get("rebuilt_shard_ids", [])
+    copied = sorted(s for take in copies.values() for s in take)
     rebuilder.call("VolumeEcShardsMount",
                    {"volume_id": vid, "collection": collection,
                     "shard_ids": rebuilt})
@@ -327,7 +389,8 @@ def do_ec_rebuild(env: CommandEnv, vid: int, collection: str = "") -> dict:
                        {"volume_id": vid, "collection": collection,
                         "shard_ids": stale})
     return {"volume_id": vid, "rebuilt": rebuilt,
-            "rebuilder": rebuilder_id, "copied": sorted(copied),
+            "rebuilder": rebuilder_id, "copied": copied,
+            "copy_fanout": _most_at_once(copying),
             # repair-IO accounting (bytes_read, plan_kind, read_shards,
             # executor): operators see the clay/LRC reduced-read plans
             # in the verb output, mirrored by the /metrics counters

@@ -121,6 +121,12 @@ def node_grpc(dn: dict) -> str:
     return f"{host}:{dn.get('grpc_port', 0)}"
 
 
+def node_tcp(dn: dict) -> str:
+    """The node's raw-TCP frame port (volume_server/tcp.py)."""
+    host = dn.get("ip") or dn["id"].split(":")[0]
+    return f"{host}:{dn.get('tcp_port', 0)}"
+
+
 def parse_flags(args: list[str]) -> dict[str, str]:
     """-volumeId 3 -collection x -force  ->  {volumeId: '3', ...}."""
     out: dict[str, str] = {}

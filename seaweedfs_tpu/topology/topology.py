@@ -224,6 +224,7 @@ class Topology:
                     rd["data_nodes"].append({
                         "id": dn.id, "ip": dn.ip, "port": dn.port,
                         "grpc_port": dn.grpc_port,
+                        "tcp_port": dn.tcp_port,
                         "public_url": dn.public_url,
                         "max_volumes": dn.max_volumes,
                         # mid-churn guard: a node swept between this
@@ -252,6 +253,7 @@ def from_topology_dict(d: dict, **topo_kw) -> Topology:
                     dcd["id"], rd["id"], nd["id"], ip=nd.get("ip", ""),
                     port=nd.get("port", 0),
                     grpc_port=nd.get("grpc_port", 0),
+                    tcp_port=nd.get("tcp_port", 0),
                     public_url=nd.get("public_url", ""),
                     max_volumes=nd.get("max_volumes", 7))
                 volumes = [VolumeInfo(**v) for v in nd.get("volumes", [])]

@@ -23,12 +23,14 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import threading
 import time
 import urllib.parse
 from concurrent.futures import ThreadPoolExecutor
 
 from ..pb.rpc import POOL, RpcError, RpcServer, from_b64, to_b64
+from ..pb.rpc import tls_enabled as rpc_tls_enabled
 from ..storage import ec as ec_pkg
 from ..storage.ec.layout import DEFAULT_GEOMETRY, to_ext
 from ..storage.needle import Needle
@@ -52,6 +54,11 @@ EC_LOCATION_STALENESS = 11.0  # the freshest staleness tier (store_ec.go:227)
 # burst, short enough that a just-heartbeated volume becomes reachable
 # within one pulse
 NEGATIVE_LOOKUP_TTL = 1.0
+
+
+# what a frame-port file copy may fetch: a volume's EC files
+# (wide stripes reach .ec23 and beyond)
+_EC_FILE_EXT = re.compile(r"\.(ec[0-9]{2,3}|ecx|ecj|vif)")
 
 
 def sendfile_enabled() -> bool:
@@ -1719,7 +1726,10 @@ class VolumeServer:
 
         With `repair_planes_of` (the lost shard of a single clay loss,
         set by the shell's ec.rebuild) only each helper's repair planes
-        are copied, to a plane file (`_ec_copy_planes`)."""
+        are copied, to a plane file (`_ec_copy_planes`).
+
+        With `source_tcp` (the source's frame port, set by the shell)
+        each file comes over that port (`_pull_file`), not CopyFile."""
         vid = int(req["volume_id"])
         collection = req.get("collection", "")
         base = self._base_path(vid, collection)
@@ -1735,13 +1745,9 @@ class VolumeServer:
             tmp = base + ext + ".tmp"
             try:
                 with open(tmp, "wb") as f:
-                    for r in src.stream("CopyFile", iter([{
-                            "volume_id": vid, "collection": collection,
-                            "ext": ext}])):
-                        data = r["file_content"]
-                        with tracing.stage("write"):
-                            f.write(data)
-                        tracing.add("bytes", len(data))
+                    self._pull_file(req, src, {
+                        "volume_id": vid, "collection": collection,
+                        "ext": ext}, f)
             except RpcError:
                 if os.path.exists(tmp):
                     os.remove(tmp)
@@ -1782,20 +1788,12 @@ class VolumeServer:
             for s in req.get("shard_ids", []):
                 dest = plane_file(base, int(s), lost)
                 tmp = dest + ".tmp"
-                got = 0
                 with open(tmp, "wb") as f:
-                    for r in src.stream("CopyFile", iter([{
-                            "volume_id": req["volume_id"],
-                            "collection": req.get("collection", ""),
-                            "ext": to_ext(int(s)),
-                            "repair_planes_of": lost}])):
-                        data = r["file_content"]
-                        got += len(data)
-                        tracing.add("bytes", len(data))
-                        if got > want:
-                            break
-                        with tracing.stage("write"):
-                            f.write(data)
+                    got = self._pull_file(req, src, {
+                        "volume_id": req["volume_id"],
+                        "collection": req.get("collection", ""),
+                        "ext": to_ext(int(s)),
+                        "repair_planes_of": lost}, f, limit=want)
                 if got != want:
                     raise RpcError(
                         f"repair planes of shard {s} for shard {lost} from "
@@ -1811,6 +1809,41 @@ class VolumeServer:
                     os.remove(p)
             raise
         return {}
+
+    def _pull_file(self, req: dict, src, fields: dict, f,
+                   limit: "int | None" = None) -> int:
+        """One file of a VolumeEcShardsCopy into the open file `f`; ->
+        bytes received, stopping once more than `limit` have arrived
+        (none written past it).  Over the source's frame port when the
+        request names one (`source_tcp`) and gRPC runs without TLS: the
+        bytes leave by sendfile and land by recv_into, outside the
+        interpreter lock (tcp.fetch_file).  A source that does not know
+        the file copy, or whose port refuses the connection, is asked
+        over CopyFile instead, as is every source under TLS."""
+        from .tcp import CopyRefused, fetch_file
+        addr = req.get("source_tcp", "")
+        if addr and not rpc_tls_enabled():
+            try:
+                return fetch_file(addr, fields, f, limit)
+            except CopyRefused as e:
+                if not str(e).startswith("unknown op"):
+                    raise RpcError(f"{fields['ext']} from {addr}: {e}") \
+                        from None
+            except ConnectionRefusedError:
+                pass
+            except OSError as e:
+                raise RpcError(f"{fields['ext']} from {addr}: "
+                               f"{type(e).__name__}: {e}") from None
+        got = 0
+        for r in src.stream("CopyFile", iter([fields])):
+            data = r["file_content"]
+            got += len(data)
+            tracing.add("bytes", len(data))
+            if limit is not None and got > limit:
+                break
+            with tracing.stage("write"):
+                f.write(data)
+        return got
 
     def _rpc_ec_delete(self, req: dict) -> dict:
         """VolumeEcShardsDelete: remove shard files, and the index files
@@ -1982,3 +2015,75 @@ class VolumeServer:
                 break
             tracing.add("bytes", chunk.nbytes)
             yield {"file_content": memoryview(chunk.reshape(-1))}
+
+    def tcp_copy_file(self, req: dict, sock, start) -> None:
+        """CopyFile over the frame port ('C', volume_server/tcp.py) for
+        VolumeEcShardsCopy: the EC files of a volume only, and not while
+        gRPC runs under TLS (the frame port has none).  Calls `start()`
+        once the request checks out, then sends the file: whole by
+        os.sendfile, or with `repair_planes_of` the chunks of
+        `_copy_planes`.  With the request's `trace_id` the copy is a
+        `VolumeServer/CopyFile` span under `parent_span_id`, tagged
+        `transport: tcp`, carrying `send_s`, `frame_s`, `bytes`,
+        `raw_bytes` (and the plane gather's `read_s`)."""
+        tid = tracing.clamp_id(str(req.get("trace_id", "")))
+        if not (tid and tracing.enabled()):
+            self._send_copy(req, sock, start)
+            return
+        sid = tracing.new_span_id()
+        tags: dict = {}
+        t0 = time.time()            # span start: wall
+        p0 = time.perf_counter()    # duration: monotonic
+        status = "ok"
+        try:
+            with tracing.trace_scope(tid, sid, tags):
+                self._send_copy(req, sock, start)
+        except BaseException:
+            status = "error"
+            raise
+        finally:
+            if self.tracer is not None:
+                self.tracer.record(
+                    "VolumeServer/CopyFile", tid, t0,
+                    time.perf_counter() - p0, status=status,
+                    slow_log=False, span_id=sid,
+                    parent_id=tracing.clamp_id(
+                        str(req.get("parent_span_id", ""))),
+                    transport="tcp", **tracing.copy_tags(tags))
+
+    def _send_copy(self, req: dict, sock, start) -> None:
+        from .tcp import (COPY_CHUNK, end_copy, send_copy_chunk,
+                          sendfile_copy_chunks)
+        if rpc_tls_enabled():
+            raise ValueError("no file copy over the frame port under "
+                             "TLS: use CopyFile")
+        ext = str(req.get("ext", ""))
+        collection = str(req.get("collection", ""))
+        if not _EC_FILE_EXT.fullmatch(ext) or "/" in collection:
+            raise ValueError(f"no frame-port copy of {collection!r} "
+                             f"{ext!r}: EC files of a volume only")
+        base = self._base_path(int(req["volume_id"]), collection)
+        path = base + ext
+        if not os.path.exists(path):
+            raise ValueError(f"{path} not found")
+        if req.get("repair_planes_of") is None:
+            with open(path, "rb") as f:
+                start()
+                if sendfile_enabled():
+                    sendfile_copy_chunks(sock, f)
+                    return
+                while chunk := f.read(COPY_CHUNK):
+                    send_copy_chunk(sock, chunk)
+                    tracing.add("bytes", len(chunk))
+                    tracing.add("raw_bytes", len(chunk))
+                end_copy(sock)
+            return
+        # a generator: its checks run at the first next(), before start()
+        chunks = self._copy_planes(base, path, req)
+        msg = next(chunks, None)
+        start()
+        while msg is not None:
+            send_copy_chunk(sock, msg["file_content"])
+            tracing.add("raw_bytes", len(msg["file_content"]))
+            msg = next(chunks, None)
+        end_copy(sock)

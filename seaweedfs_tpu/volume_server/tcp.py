@@ -10,11 +10,11 @@ is a single recv/send pair per op.
 
 Frame (client -> server), little-endian:
     op:u8 ('W' write | 'X' extended write | 'R' read | 'G' ranged read
-           | 'D' delete)
+           | 'D' delete | 'C' file copy)
     fid_len:u16, fid bytes
     jwt_len:u16, jwt bytes
     body_len:u32, body bytes            (writes; 'G': offset:u64 len:u32;
-                                         0 otherwise)
+                                         'C': JSON request; 0 otherwise)
 
 The ranged read ('G') carries its byte window in the body slot
 (pack_range_body/unpack_range_body) and replies with exactly those
@@ -53,6 +53,17 @@ Reply (server -> client):
     status:u8 (0 ok, 1 error)
     payload_len:u32, payload bytes      (R: needle data; W/D: json ack;
                                          error: message)
+
+The file copy ('C') carries a JSON request in the body slot
+({volume_id, collection, ext, optional repair_planes_of, optional
+trace_id and parent_span_id}) and holds its connection to the end of
+one file: after an ok reply with an empty payload the file's bytes
+follow as chunks (length:u32, bytes), ended by a zero length, and the
+server closes.  A failure after the ok reply closes the connection
+with no terminator, so the client sees the file cut short.  It is
+CopyFile (volume_server.proto:60) for the EC shard copy, with the bytes
+moved by sendfile and recv_into outside the interpreter lock.  It is
+refused while gRPC runs under TLS, where CopyFile stays the only way.
 
 The port is ephemeral and advertised through the volume-server heartbeat
 ("tcp_port"), flowing into topology DataNodes and lookup/assign replies
@@ -171,6 +182,126 @@ def unpack_range_body(body: bytes) -> tuple[int, int]:
     if len(body) != _RANGE_BODY.size:
         raise ValueError("ranged read frame body must be 12 bytes")
     return _RANGE_BODY.unpack(body)
+
+
+_CHUNK_LEN = struct.Struct("<I")
+# the largest chunk a file copy sends: whole files leave by sendfile in
+# chunks of this size, and the receiver's buffer is at most this large
+COPY_CHUNK = 8 << 20
+
+
+class CopyRefused(RuntimeError):
+    """The source answered a file copy with an error (its message)."""
+
+
+def send_copy_chunk(sock: socket.socket, data) -> None:
+    """One chunk of a file copy from a buffer (the plane branch)."""
+    from ..util import tracing
+    with tracing.stage("frame"):
+        head = _CHUNK_LEN.pack(len(data))
+    with tracing.stage("send"):
+        sock.sendall(head)
+        sock.sendall(data)
+
+
+def sendfile_copy_chunks(sock: socket.socket, f) -> int:
+    """The whole of the open file `f` as file-copy chunks, each sent by
+    os.sendfile (page cache to socket, no interpreter lock held); then
+    the terminator.  -> bytes sent."""
+    from ..util import tracing
+    size = os.fstat(f.fileno()).st_size
+    off = 0
+    while off < size:
+        n = min(COPY_CHUNK, size - off)
+        with tracing.stage("frame"):
+            head = _CHUNK_LEN.pack(n)
+        with tracing.stage("send"):
+            sock.sendall(head)
+            end = off + n
+            while off < end:
+                sent = os.sendfile(sock.fileno(), f.fileno(), off,
+                                   end - off)
+                if not sent:
+                    raise ConnectionError(
+                        f"{f.name} ended at {off} of {size} B")
+                off += sent
+        tracing.add("bytes", n)
+        tracing.add("raw_bytes", n)
+    end_copy(sock)
+    return size
+
+
+def end_copy(sock: socket.socket) -> None:
+    sock.sendall(_CHUNK_LEN.pack(0))
+
+
+def _recv_into_exact(sock: socket.socket, view: memoryview) -> None:
+    got = 0
+    while got < len(view):
+        n = sock.recv_into(view[got:])
+        if not n:
+            raise ConnectionError("peer closed")
+        got += n
+
+
+def fetch_file(addr: str, req: dict, out, limit: "int | None" = None
+               ) -> int:
+    """Pull one file from the volume server at frame port `addr` ('C',
+    see the module docstring) into the open binary file `out`, chunk by
+    chunk through recv_into and write, neither of which holds the
+    interpreter lock while it copies.  The thread's open span gets
+    `recv_s`, `write_s`, `frame_s`, `bytes` and `raw_bytes`.  Stops once
+    more than `limit` bytes have arrived, writing none past it.
+    -> bytes received.  Raises CopyRefused with the source's message,
+    ConnectionError if the file arrives cut short, OSError on the
+    transport."""
+    from ..util import faults, tracing
+    from ..util.retry import default_connect_timeout, default_rpc_timeout
+    if faults.ACTIVE:
+        faults.raise_if_planned("tcp.connect", addr)
+    tid = tracing.current_trace_id() if tracing.enabled() else ""
+    if tid:
+        # the source's CopyFile span joins the caller's trace
+        req = {**req, "trace_id": tid,
+               "parent_span_id": tracing.current_span_id()}
+    host, _, port = addr.rpartition(":")
+    with socket.create_connection((host, int(port)),
+                                  timeout=default_connect_timeout()) \
+            as sock:
+        # an idle bound per recv, not a deadline on the whole file
+        sock.settimeout(default_rpc_timeout())
+        write_frame(sock, "C", "",
+                    body=json.dumps(req, separators=(",", ":")).encode())
+        with tracing.stage("recv"):
+            status, payload = read_reply(sock)
+        if status != 0:
+            raise CopyRefused(payload.decode(errors="replace"))
+        head = bytearray(_CHUNK_LEN.size)
+        buf = None
+        got = 0
+        while True:
+            with tracing.stage("recv"):
+                _recv_into_exact(sock, memoryview(head))
+            with tracing.stage("frame"):
+                (n,) = _CHUNK_LEN.unpack(head)
+            if not n:
+                return got
+            if n > COPY_CHUNK:
+                raise ConnectionError(
+                    f"copy chunk of {n} B from {addr} exceeds "
+                    f"{COPY_CHUNK}")
+            if buf is None or len(buf) < n:
+                buf = memoryview(bytearray(n))
+            view = buf[:n]
+            with tracing.stage("recv"):
+                _recv_into_exact(sock, view)
+            got += n
+            tracing.add("bytes", n)
+            tracing.add("raw_bytes", n)
+            if limit is not None and got > limit:
+                return got
+            with tracing.stage("write"):
+                out.write(view)
 
 
 class FrameTooLarge(ValueError):
@@ -325,6 +456,9 @@ class TcpDataServer:
                         conn, str(e),
                         lambda msg: write_reply(conn, 1, msg))
                     return
+                if op == "C":
+                    self._serve_copy(conn, body)
+                    return
                 try:
                     payload = self._handle(op, fid, jwt, body)
                     write_reply(conn, 0, payload)
@@ -359,6 +493,9 @@ class TcpDataServer:
                         conn, str(e),
                         lambda msg: fp.write_reply(ctx, 1, msg))
                     return
+                if op == ord("C"):
+                    self._serve_copy(conn, body)
+                    return
                 try:
                     payload = self._handle(chr(op), fid_b.decode(),
                                            jwt_b.decode(), body)
@@ -375,6 +512,25 @@ class TcpDataServer:
                 conn.close()
             except OSError:
                 pass
+
+    def _serve_copy(self, conn: socket.socket, body: bytes) -> None:
+        """A file copy ('C'): the volume server checks the request and
+        calls `start()` (the ok reply) before the first chunk; an error
+        before that is the error reply, one after it cuts the stream.
+        The caller closes the connection."""
+        started = False
+
+        def start() -> None:
+            nonlocal started
+            write_reply(conn, 0, b"")
+            started = True
+        try:
+            self.vs.tcp_copy_file(json.loads(body), conn, start)
+        except Exception as e:
+            if not started:
+                write_reply(conn, 1, str(e).encode())
+            else:
+                LOG.warning("file copy cut after it started: %s", e)
 
     def _handle(self, op: str, fid: str, jwt: str, body: bytes) -> bytes:
         if op == "W":

@@ -6,6 +6,7 @@ and CopyFile without the field streams whole files as it always did."""
 import glob
 import json
 import os
+import threading
 
 import numpy as np
 import pytest
@@ -170,6 +171,58 @@ def test_a_source_that_ignores_the_field_fails_the_copy(sealed,
     out = json.loads(shell.run_command(
         env, f"ec.rebuild -volumeId {vid}"))["rebuilt"][0]
     assert out["rebuilt"] == [lost]
+    assert _read(_shard_paths(c, vid)[lost]) == shards[lost]
+    assert _leftovers(c, vid) == []
+
+
+def test_a_failed_source_waits_for_the_other_copies(sealed, monkeypatch):
+    """The copies run at once: one source streams whole shards for a
+    plane request while the others' streams are held until that copy
+    has failed.  ec.rebuild raises the error naming the failing source
+    only once the late copies have ended, then removes every plane file,
+    theirs too, and a clean ec.rebuild repairs the shard."""
+    c, env, vid, shards, _ = sealed.rearm()
+    lost = 5
+    _lose(c, env, vid, lost)
+    failed = threading.Event()
+    lock = threading.Lock()
+    roles: dict[str, str] = {}
+    late: list[bool] = []
+    planes = VolumeServer._copy_planes
+    copy_planes = VolumeServer._ec_copy_planes
+
+    def source(self, base, path, req):
+        with lock:
+            role = roles.setdefault(self.grpc_address,
+                                    "late" if roles else "fail")
+        if role == "fail":
+            with open(path, "rb") as f:
+                while chunk := f.read(1 << 20):
+                    yield {"file_content": chunk}
+            return
+        late.append(failed.wait(timeout=30))
+        yield from planes(self, base, path, req)
+
+    def rebuilder(self, req, base, src):
+        try:
+            return copy_planes(self, req, base, src)
+        except RpcError:
+            failed.set()
+            raise
+    monkeypatch.setattr(VolumeServer, "_copy_planes", source)
+    monkeypatch.setattr(VolumeServer, "_ec_copy_planes", rebuilder)
+    with pytest.raises(RpcError) as err:
+        shell.run_command(env, f"ec.rebuild -volumeId {vid}")
+    (failing,) = [a for a, r in roles.items() if r == "fail"]
+    assert "repair planes" in str(err.value) and failing in str(err.value)
+    assert len(roles) >= 2 and late and all(late)
+    assert _leftovers(c, vid) == []
+    assert lost not in _shard_paths(c, vid)
+    monkeypatch.undo()
+    out = json.loads(shell.run_command(
+        env, f"ec.rebuild -volumeId {vid}"))["rebuilt"][0]
+    assert out["rebuilt"] == [lost]
+    assert out["copy_fanout"] == len(roles)
     assert _read(_shard_paths(c, vid)[lost]) == shards[lost]
     assert _leftovers(c, vid) == []
 

@@ -18,7 +18,7 @@ from seaweedfs_tpu.master import MasterServer
 from seaweedfs_tpu.ops.codec import RSCodec, codec_metrics
 from seaweedfs_tpu.pb.rpc import POOL, RpcServer
 from seaweedfs_tpu.shell import CommandEnv
-from seaweedfs_tpu.shell.command_ec import do_ec_encode
+from seaweedfs_tpu.shell.command_ec import do_ec_encode, do_ec_rebuild
 from seaweedfs_tpu.storage import ec
 from seaweedfs_tpu.storage.ec.layout import EcGeometry
 from seaweedfs_tpu.storage.needle import Needle
@@ -217,8 +217,36 @@ def _volume_files(directory: str, vid: int) -> list[str]:
             and f.split(".")[1] not in ("dat", "idx")]
 
 
-def test_ec_encode_copy_spans_carry_bytes_and_stages(cluster):
-    master, servers, env = cluster
+def _copy_spans(servers, tid: str) -> list[dict]:
+    """The VolumeEcShardsCopy spans of one trace, each checked, as the
+    CopyFile spans under them, for its stage tags and for bytes that all
+    came raw; the bytes sent are the bytes copied.  Every file crossed
+    the source's frame port: a CopyFile span tagged `transport: tcp`,
+    its bytes sent by sendfile."""
+    copies, sends = [], []
+    for vs in servers:
+        for s in vs.tracer.snapshot(trace_id=tid):
+            if s["name"] == "VolumeServer/VolumeEcShardsCopy":
+                for tag in ("recv_s", "frame_s", "write_s"):
+                    assert 0 < s[tag] <= s["duration_ms"] / 1e3, (tag, s)
+                # every shard byte came raw, none base64 in JSON
+                assert s["raw_bytes"] == s["bytes"], s
+                copies.append(s)
+            elif s["name"] == "VolumeServer/CopyFile" \
+                    and s["status"] == "ok":
+                assert s["transport"] == "tcp", s
+                for tag in ("send_s", "frame_s"):
+                    assert 0 < s[tag] <= s["duration_ms"] / 1e3, (tag, s)
+                assert s["raw_bytes"] == s["bytes"], s
+                sends.append(s)
+    # each file a copy pulled is one CopyFile under it
+    assert {s["parent_id"] for s in sends} <= {s["span_id"] for s in copies}
+    assert len(sends) >= len(copies)
+    assert sum(s["bytes"] for s in sends) == sum(s["bytes"] for s in copies)
+    return copies
+
+
+def _seal(master, servers, env) -> tuple[int, dict]:
     vid = None
     for i in range(12):
         fid = operation.assign_and_upload(master.grpc_address,
@@ -228,12 +256,20 @@ def test_ec_encode_copy_spans_carry_bytes_and_stages(cluster):
         vs.heartbeat_now()
     tid = tracing.new_trace_id()
     with tracing.trace_scope(tid):
-        do_ec_encode(env, vid)
-    copies = sends = 0
-    sent = copied = 0
+        out = do_ec_encode(env, vid)
+    return vid, {"tid": tid, **out}
+
+
+def test_ec_encode_copy_spans_carry_bytes_and_stages(cluster):
+    """The destinations copy at once, each under the verb's trace: every
+    copy is found by the trace id, and carries what it pulled."""
+    master, servers, env = cluster
+    vid, out = _seal(master, servers, env)
+    copies = _copy_spans(servers, out["tid"])
+    assert len(copies) == out["copy_fanout"] == 3
+    copied = 0
     for vs in servers:
-        spans = vs.tracer.snapshot(trace_id=tid)
-        mine = [s for s in spans
+        mine = [s for s in vs.tracer.snapshot(trace_id=out["tid"])
                 if s["name"] == "VolumeServer/VolumeEcShardsCopy"]
         if mine:
             # the files this server pulled are the EC files it now holds
@@ -241,22 +277,35 @@ def test_ec_encode_copy_spans_carry_bytes_and_stages(cluster):
                 vs.store.locations[0].directory, vid))
             assert sum(s["bytes"] for s in mine) == want > 0
             copied += want
-        for s in mine:
-            copies += 1
-            for tag in ("recv_s", "frame_s", "write_s"):
-                assert 0 < s[tag] <= s["duration_ms"] / 1e3, (tag, s)
-            # every shard byte came raw, none base64 in JSON
-            assert s["raw_bytes"] == s["bytes"], s
-        for s in spans:
-            if s["name"] != "VolumeServer/CopyFile" or s["status"] != "ok":
-                continue
-            sends += 1
-            sent += s["bytes"]
-            for tag in ("read_s", "frame_s"):
-                assert 0 < s[tag] <= s["duration_ms"] / 1e3, (tag, s)
-            assert s["raw_bytes"] == s["bytes"], s
-    assert copies >= 2 and sends >= copies
-    assert sent == copied
+    assert sum(s["bytes"] for s in copies) == copied
+
+
+def test_ec_rebuild_copy_spans_carry_bytes_and_stages(cluster):
+    """The sources' copies to the rebuilder run at once, each under the
+    verb's trace id and span: every copy names the verb's span as its
+    parent, and the bytes sent are the whole shards copied."""
+    master, servers, env = cluster
+    vid, _ = _seal(master, servers, env)
+    lost = 0
+    holder = next(vs for vs in servers if os.path.exists(os.path.join(
+        vs.store.locations[0].directory, f"{vid}.ec00")))
+    size = os.path.getsize(os.path.join(holder.store.locations[0].directory,
+                                        f"{vid}.ec00"))
+    client = env.volume_server(holder.grpc_address)
+    client.call("VolumeEcShardsUnmount", {"volume_id": vid,
+                                         "shard_ids": [lost]})
+    client.call("VolumeEcShardsDelete", {"volume_id": vid, "collection": "",
+                                        "shard_ids": [lost]})
+    for vs in servers:
+        vs.heartbeat_now()
+    tid, sid = tracing.new_trace_id(), tracing.new_span_id()
+    with tracing.trace_scope(tid, sid):
+        out = do_ec_rebuild(env, vid)
+    assert out["rebuilt"] == [lost]
+    copies = _copy_spans(servers, tid)
+    assert len(copies) == out["copy_fanout"] >= 2
+    assert {s["parent_id"] for s in copies} == {sid}
+    assert sum(s["bytes"] for s in copies) == len(out["copied"]) * size
 
 
 # -- codec registry stages -------------------------------------------------
